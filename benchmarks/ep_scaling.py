@@ -81,6 +81,9 @@ print("RESULT " + json.dumps({"wall_s": wall, "capacity": cap, "S": S,
 def _time_shards(n):
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    # Emulated CPU devices: the child must never reach for an accelerator
+    # the parent process may already hold.
+    env["JAX_PLATFORMS"] = "cpu"
     env.pop("XLA_FLAGS", None)
     src = SCRIPT % dict(n=n, iters=ITERS, d=D_MODEL, T=N_TOKENS, E=N_EXPERTS)
     r = subprocess.run([sys.executable, "-c", src], env=env,

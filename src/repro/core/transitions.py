@@ -45,7 +45,8 @@ class PendingPromotion:
     # THIS copy's result arrays (one per bank leaf). Readiness must be
     # probed on these — ``bank.hi`` is overwritten by every later
     # ``_issue_copy``, so peeking the bank would let an older promotion
-    # publish on a newer copy's completion (and vice versa).
+    # publish on a newer copy's completion (and vice versa). A later copy
+    # donates them (``write_hi_slot`` writes in place); see ``_is_ready``.
     arrays: tuple = ()
     # Flight-recorder lifecycle: async-span correlation id and the engine-
     # clock issue timestamp (publish latency = publish ts − issue ts).
@@ -219,13 +220,13 @@ class TransitionManager:
             ensure = getattr(self.host_hi, "ensure_hi", None)
             if ensure is not None:
                 ensure(layer, expert)
-            new_hi = {}
-            for name, leaf in self.bank.hi.items():
-                w = jnp.asarray(self.host_hi[name][layer, expert]).astype(
-                    leaf.dtype)
-                new_hi[name] = write_hi_slot(leaf, jnp.int32(layer),
-                                             jnp.int32(slot), w)
-            return new_hi
+            # Every host read before the first write: the writes donate the
+            # bank's leaves, so nothing may fail between them.
+            rows = {name: jnp.asarray(self.host_hi[name][layer, expert])
+                    .astype(leaf.dtype) for name, leaf in self.bank.hi.items()}
+            return {name: write_hi_slot(leaf, jnp.int32(layer),
+                                        jnp.int32(slot), rows[name])
+                    for name, leaf in self.bank.hi.items()}
 
         seed = self.injector.seed if self.injector is not None else 0
         try:
@@ -312,7 +313,8 @@ class TransitionManager:
             ready = wait or all(_is_ready(a) for a in p.arrays)
             if ready and wait:
                 for a in p.arrays:
-                    jax.block_until_ready(a)
+                    if not a.is_deleted():
+                        jax.block_until_ready(a)
             if not ready:
                 still.append(p)
                 continue
@@ -452,8 +454,7 @@ class TransitionManager:
 
 
 def _is_ready(arr) -> bool:
-    try:
-        return arr.is_ready()
-    except AttributeError:
-        jax.block_until_ready(arr)
-        return True
+    """A copy's result is ready once computed — or once a later slot write
+    consumed it by donation: that write runs after it, and every forward
+    reads the live leaf the later write produces."""
+    return arr.is_deleted() or arr.is_ready()

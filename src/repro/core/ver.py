@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import functools
 from typing import Dict
 
 import jax
@@ -174,10 +175,13 @@ def expert_lo_nbytes(expert_weights_shapes: Dict[str, tuple], lo_bits: int,
 # happen in place (the TPU analogue of copying into a preallocated pool slot).
 # ---------------------------------------------------------------------------
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=0)
 def write_hi_slot(hi_leaf: jax.Array, layer: jax.Array, slot: jax.Array,
                   w: jax.Array) -> jax.Array:
-    """Copy one expert's hi weights into pool slot (layer, slot).
+    """Copy one expert's hi weights into pool slot (layer, slot), in place:
+    ``hi_leaf`` is donated, so a burst of promotions never holds more than
+    one copy of the pool (an undonated write allocates the whole (L, slots,
+    K, N) leaf anew per promotion).
 
     This is the 'async copy on stream_mig': the serve step in flight does not
     depend on this buffer (the slot is unpublished), so XLA is free to overlap

@@ -1,9 +1,11 @@
 """Jitted public wrappers for the Pallas kernels + the serving-path
 dispatchers that pick an execution backend per environment.
 
-On TPU the kernels compile natively; everywhere else (this CPU container)
-they execute in ``interpret=True`` mode, which runs the kernel body in
-Python for correctness validation against ``ref.py``.
+On TPU the kernels compile natively (Mosaic) and never fall back: a shape
+the kernel cannot tile raises, and no path swaps in the ``ref.py`` oracle
+behind the caller's back. Everywhere else (CPU tests) they execute in
+``interpret=True`` mode, which runs the kernel body in Python for
+correctness validation against ``ref.py``.
 
 Two dispatch axes for the MoE decode hot path, each overridable by env:
 
@@ -136,8 +138,7 @@ def ragged_dense_ffn_op(xs: jax.Array, tile_eid: jax.Array, bank: dict,
     The Pallas backend reuses the fused mixed-precision kernel in all-hi
     mode — every tile reads its expert's dense weights through the hi-pool
     operand while a placeholder lo tier holds one zero expert and is never
-    streamed (the per-tile DMA hold maps pin it to block 0). Falls back to
-    the jnp oracle when the kernel's tiling constraints reject the shapes.
+    streamed (the per-tile DMA hold maps pin it to block 0).
     Returns (Tt·bm, D)."""
     be = backend if backend is not None else moe_gemm_backend()
     w_gate, w_up, w_down = bank["w_gate"], bank["w_up"], bank["w_down"]
@@ -148,15 +149,11 @@ def ragged_dense_ffn_op(xs: jax.Array, tile_eid: jax.Array, bank: dict,
                                 jnp.zeros((1, k // group, n), w_gate.dtype))
         gp, gs = zero_lo(K, F)
         dp_, ds = zero_lo(F, K)
-        ones = jnp.ones_like(tile_eid)
-        try:
-            return ragged_quant_ffn(
-                xs, jnp.zeros_like(tile_eid), tile_eid, ones,
-                gp, gs, gp, gs, dp_, ds,
-                w_gate, w_up, w_down,
-                bits=8, group=group, bm=bm, interpret=_interpret_default())
-        except ValueError:   # tiling constraints — oracle is always valid
-            pass
+        return ragged_quant_ffn(
+            xs, jnp.zeros_like(tile_eid), tile_eid, jnp.ones_like(tile_eid),
+            gp, gs, gp, gs, dp_, ds,
+            w_gate, w_up, w_down,
+            bits=8, group=group, bm=bm, interpret=_interpret_default())
     return _ref.ragged_dense_ffn_ref(xs, tile_eid, w_gate, w_up, w_down,
                                      bm=bm)
 

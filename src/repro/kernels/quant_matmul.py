@@ -26,8 +26,10 @@ Three kernels:
   grouped w_down GEMM rides the same tile maps.
 
 Tiling: grid (tiles, N/bn, K/bk); K is the innermost (sequential) axis with
-f32 VMEM accumulators. bk is a multiple of the quantization group so each
-K-tile sees whole scale groups.
+f32 VMEM accumulators. bk is a whole number of quantization groups, chosen
+by ``_k_tile`` so that the scales block either spans a multiple of 16 rows
+or the whole K/group axis — the TPU lowering refuses anything else for a
+bf16 block (at group 64 and K ≤ 1024, one K step covers the contraction).
 """
 from __future__ import annotations
 
@@ -36,6 +38,22 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+
+#: Second-minor block rows Mosaic accepts for a bf16 operand (its sublane
+#: tile). The scales block (bk // group, bn) must span a multiple of this
+#: many rows, or the whole K // group axis.
+_SCALE_ROWS = 16
+
+
+def _k_tile(K: int, group: int, pref: int) -> int:
+    """Contraction tile: the largest multiple of ``_SCALE_ROWS`` scale
+    groups not above ``pref`` (at least one such multiple) when it divides
+    ``K``, else the whole of ``K``. Either way each K tile sees whole scale
+    groups and its scales block is one the TPU lowering accepts."""
+    step = _SCALE_ROWS * group
+    bk = max(step, pref // step * step)
+    return bk if K % bk == 0 else K
 
 
 def _unpack_tile(wp: jax.Array, bits: int) -> jax.Array:
@@ -99,8 +117,7 @@ def quant_matmul(x: jax.Array, packed: jax.Array, scales: jax.Array, *,
     M, K = x.shape
     epb = 8 // bits
     N = packed.shape[1]
-    bm, bn, bk = min(bm, M), min(bn, N), min(bk, K)
-    bk = max(group, bk // group * group)
+    bm, bn, bk = min(bm, M), min(bn, N), _k_tile(K, group, bk)
     if M % bm or N % bn or K % bk:
         raise ValueError(f"shape ({M},{K})x({K},{N}) not tileable by "
                          f"({bm},{bn},{bk})")
@@ -116,9 +133,7 @@ def quant_matmul(x: jax.Array, packed: jax.Array, scales: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
-        scratch_shapes=[pl.ArrayRef((bm, bn), jnp.float32)]
-        if hasattr(pl, "ArrayRef") else
-        [_vmem_scratch((bm, bn), jnp.float32)],
+        scratch_shapes=[_vmem_scratch((bm, bn), jnp.float32)],
         interpret=interpret,
     )(x, packed, scales)
 
@@ -151,8 +166,7 @@ def grouped_quant_matmul(xg: jax.Array, packed: jax.Array, scales: jax.Array,
     E, C, K = xg.shape
     epb = 8 // bits
     N = packed.shape[2]
-    bm, bn, bk = min(bm, C), min(bn, N), min(bk, K)
-    bk = max(group, bk // group * group)
+    bm, bn, bk = min(bm, C), min(bn, N), _k_tile(K, group, bk)
     if C % bm or N % bn or K % bk:
         raise ValueError(f"({E},{C},{K})x({K},{N}) not tileable by "
                          f"({bm},{bn},{bk})")
@@ -307,8 +321,8 @@ def ragged_quant_ffn(xs: jax.Array, tile_lo: jax.Array, tile_hi: jax.Array,
     has_hi = hi_gate is not None and hi_gate.shape[0] > 0
     bn_f = _fit_tile(F, bn)
     bn_d = _fit_tile(D, bn)
-    bk_k = _fit_tile(K, max(group, min(bk, K) // group * group))
-    bk_f = _fit_tile(F, max(group, min(bk, F) // group * group))
+    bk_k = _k_tile(K, group, bk)
+    bk_f = _k_tile(F, group, bk)
     if K % bk_k or K % group or F % bn_f or F % bk_f or F % group or D % bn_d:
         raise ValueError(f"(K={K}, F={F}, D={D}) not tileable by "
                          f"(bk={bk_k}/{bk_f}, bn={bn_f}/{bn_d}, g={group})")

@@ -60,6 +60,44 @@ def ep_context(mesh, model_axis: str = "model") -> DistContext:
                        tokens_dp_sharded=True, tokens_ep_sharded=True)
 
 
+def place_for_serving(dist: DistContext, params: dict, banks) -> None:
+    """Commit serving state to ``dist.mesh`` once, in place: parameters
+    replicated, and each quantized expert bank split over the model axis
+    along its expert dim (lo tier) and hi-slot dim (hi tier, when the slots
+    divide) — the layout the MoE ``shard_map`` reads, so no step re-sends
+    the weights from device 0. Leaves are swapped inside their containers
+    because the residency backend and the EP coordinator keep references
+    to those dicts and banks."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.core.ver import ExpertBankQ
+
+    repl = NamedSharding(dist.mesh, P())
+    split = NamedSharding(dist.mesh, P(None, dist.model_axis))
+
+    def put(d: dict) -> None:
+        for k, v in d.items():
+            if isinstance(v, dict):
+                put(v)
+            else:
+                d[k] = jax.tree_util.tree_map(
+                    lambda a: jax.device_put(a, repl), v)
+
+    put(params)
+    for bank in (banks or {}).values():
+        if not isinstance(bank, ExpertBankQ):
+            continue
+        for name, qt in bank.lo.items():
+            bank.lo[name] = dataclasses.replace(
+                qt, packed=jax.device_put(qt.packed, split),
+                scales=jax.device_put(qt.scales, split))
+        n_hi = bank.slot_owner.shape[-1]
+        hi = split if n_hi and n_hi % dist.model_size == 0 else repl
+        for name, a in bank.hi.items():
+            bank.hi[name] = jax.device_put(a, hi)
+
+
 def get_dist() -> Optional[DistContext]:
     return getattr(_STATE, "ctx", None)
 

@@ -1,11 +1,17 @@
 """Serving launcher.
 
-On this CPU container it runs the reduced configs end to end (the full
-configs are exercised by the dry-run); on a real TPU slice the same command
-serves the full config under the production mesh:
+With no ``--full`` it serves the reduced config of ``--arch`` (CPU-sized:
+narrower widths, fewer layers and experts); ``--full`` serves the published
+widths, which needs an accelerator (one TPU v5e holds
+granite-moe-1b-a400m). It drives the same ``InferenceEngine`` loop either
+way, on one device, or over ``--ep-shards`` devices of an expert-parallel
+mesh:
 
     python -m repro.launch.serve --arch granite-moe-1b-a400m \
         --backend dynaexq --batch 4 --prompt-len 32 --new-tokens 16 [--full]
+
+``chip_smoke.py`` at the repository root is the one-chip end-to-end check
+of the full-width path.
 """
 import argparse
 import time
@@ -14,6 +20,7 @@ import jax
 
 from repro.configs import ARCH_IDS, get_config
 from repro.core import ControllerConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.dist import ep_context
 from repro.launch.mesh import make_ep_mesh
 from repro.models import init_params
@@ -56,7 +63,7 @@ def _fault_plan(args):
     return FaultPlan.parse(args.fault_plan, seed=args.fault_seed)
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-moe-1b-a400m", choices=ARCH_IDS)
     ap.add_argument("--backend", "--mode", dest="backend", default="dynaexq",
@@ -69,7 +76,8 @@ def main():
     ap.add_argument("--hbm-gb", type=float, default=None,
                     help="derive n_hi from a device envelope instead")
     ap.add_argument("--full", action="store_true",
-                    help="full (assigned) config — needs a real accelerator")
+                    help="serve the published widths instead of the "
+                         "reduced config (needs an accelerator)")
     ap.add_argument("--workload", default="text")
     ap.add_argument("--dense-kv", action="store_true",
                     help="dense per-slot KV rows instead of the paged pool")
@@ -171,10 +179,36 @@ def main():
                          "ranges with per-shard budgets (requires "
                          "num_experts and --n-hi divisible by the shard "
                          "count; 1 = single-device)")
-    args = ap.parse_args()
+    return ap
 
+
+def build_engine_config(args) -> EngineConfig:
+    """CLI arguments → EngineConfig (sequence budget: prompt + new tokens
+    + 8 positions of slack)."""
+    return EngineConfig(
+        max_slots=args.batch,
+        max_len=args.prompt_len + args.new_tokens + 8,
+        paged=not args.dense_kv,
+        block_tokens=args.block_tokens,
+        prefix_sharing=not args.no_prefix_sharing,
+        hbm_budget_bytes=None if args.hbm_budget_gb is None
+        else int(args.hbm_budget_gb * (1 << 30)),
+        spec_k=0 if args.no_spec else max(0, args.spec_k),
+        moe_dispatch=args.moe_dispatch,
+        row_capacity_norm=args.row_capacity,
+        promo_deadline_s=args.promo_deadline_s,
+        scheduler=SchedulerConfig(
+            qos_default=args.qos_default,
+            shed_policy=args.shed_policy,
+            prefill_chunk=args.prefill_chunk))
+
+
+def main():
+    args = build_parser().parse_args()
+
+    enable_compile_cache()
     cfg = get_config(args.arch, reduced=not args.full)
-    spec_k = 0 if args.no_spec else max(0, args.spec_k)
+    ecfg = build_engine_config(args)
     dist = None
     if args.ep_shards > 1:
         if args.ep_shards > jax.device_count():
@@ -184,7 +218,7 @@ def main():
                 f"--xla_force_host_platform_device_count=N to emulate)")
         dist = ep_context(make_ep_mesh(args.ep_shards))
     print(f"[serve] {cfg.name} backend={args.backend} "
-          f"devices={jax.device_count()} spec_k={spec_k} "
+          f"devices={jax.device_count()} spec_k={ecfg.spec_k} "
           f"ep_shards={args.ep_shards}")
     if args.stream_from:
         # Streaming cold start: only the base (non-expert) params load
@@ -203,24 +237,8 @@ def main():
     if not args.no_obs:
         from repro.obs import Observability, ObsConfig
         obs = Observability(ObsConfig(metrics_jsonl=args.metrics_jsonl))
-    engine = InferenceEngine(
-        cfg, params, build_backend(args),
-        EngineConfig(max_slots=args.batch,
-                     max_len=args.prompt_len + args.new_tokens + 8,
-                     paged=not args.dense_kv,
-                     block_tokens=args.block_tokens,
-                     prefix_sharing=not args.no_prefix_sharing,
-                     hbm_budget_bytes=None if args.hbm_budget_gb is None
-                     else int(args.hbm_budget_gb * (1 << 30)),
-                     spec_k=spec_k,
-                     moe_dispatch=args.moe_dispatch,
-                     row_capacity_norm=args.row_capacity,
-                     promo_deadline_s=args.promo_deadline_s,
-                     scheduler=SchedulerConfig(
-                         qos_default=args.qos_default,
-                         shed_policy=args.shed_policy,
-                         prefill_chunk=args.prefill_chunk)),
-        dist=dist, obs=obs)
+    engine = InferenceEngine(cfg, params, build_backend(args), ecfg,
+                             dist=dist, obs=obs)
     toks = make_prompts(args.workload, cfg.vocab_size,
                         args.batch, args.prompt_len)
     use_sampling = (args.temperature > 0 or args.top_k is not None or
@@ -244,7 +262,7 @@ def main():
           f"active_experts {st.get('active_experts', 0.0):.1f}"
           f"/{cfg.moe.num_experts if cfg.is_moe else 0}  "
           f"pad_ratio {st.get('dispatch_pad_ratio', 0.0):.2f}")
-    if spec_k:
+    if ecfg.spec_k:
         row_rounds = max(1.0, st.get("spec_row_rounds", 0.0))
         print(f"[serve] spec: accept_rate {st['accept_rate']:.2f}  "
               f"tokens/row-round {st['verified_tokens']/row_rounds:.2f} "

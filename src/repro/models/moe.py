@@ -46,6 +46,7 @@ from typing import Dict, NamedTuple, Optional, Union
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
 from repro.core.ver import ExpertBankQ
 from repro.kernels import ops as kops
@@ -569,15 +570,6 @@ def _moe_apply_sharded(params, bank, x, cfg: MoEConfig, capacity, dist,
     (PartitionSpec trees must structurally match the args; custom pytree
     metadata like QuantizedTensor's logical shape changes under slicing)."""
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
-
-    import inspect
-    # jax ≥ 0.6 renamed check_rep → check_vma; support both.
-    check_kw = "check_vma" if "check_vma" in \
-        inspect.signature(shard_map).parameters else "check_rep"
 
     mesh = dist.mesh
     mn = dist.model_size
@@ -676,7 +668,7 @@ def _moe_apply_sharded(params, bank, x, cfg: MoEConfig, capacity, dist,
         return _moe_local_ep(params, flat, rebuild, x, cfg, capacity, dist,
                              token_valid, n_rows, row_capacity, gemm, mesh,
                              mn, n_tok, e_local, nh_local, is_q, params_spec,
-                             bank_spec, shard_map, check_kw)
+                             bank_spec)
 
     x_spec = P(dist.dp_axes) if dist.tokens_dp_sharded else repl
     tv_spec = None if token_valid is None else x_spec
@@ -739,7 +731,7 @@ def _moe_apply_sharded(params, bank, x, cfg: MoEConfig, capacity, dist,
         body, mesh=mesh,
         in_specs=(params_spec, bank_spec, x_spec, tv_spec),
         out_specs=out_specs,
-        **{check_kw: False},
+        check_vma=False,
     )(params, flat, x, token_valid)
     y, counts, aux, dropped, padr = res[:5]
     rc = res[5] if want_rc else None
@@ -751,8 +743,7 @@ def _moe_apply_sharded(params, bank, x, cfg: MoEConfig, capacity, dist,
 
 def _moe_local_ep(params, flat, rebuild, x, cfg: MoEConfig, capacity, dist,
                   token_valid, n_rows, row_capacity, gemm, mesh, mn, n_tok,
-                  e_local, nh_local, is_q, params_spec, bank_spec, shard_map,
-                  check_kw):
+                  e_local, nh_local, is_q, params_spec, bank_spec):
     """The EP ragged all-to-all pipeline (see ``_moe_apply_sharded``).
 
     Wire protocol per shard pair: a (mn·S, d) row payload — block ``s`` of
@@ -914,7 +905,7 @@ def _moe_local_ep(params, flat, rebuild, x, cfg: MoEConfig, capacity, dist,
         body, mesh=mesh,
         in_specs=(params_spec, bank_spec, x_spec, tv_spec),
         out_specs=out_specs,
-        **{check_kw: False},
+        check_vma=False,
     )(params, flat, x, token_valid)
     y, counts, aux, dropped, padr = res[:5]
     rc = res[5] if want_rc else None

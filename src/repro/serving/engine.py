@@ -78,7 +78,7 @@ import numpy as np
 
 from repro.core.budget import UNBOUNDED, BudgetTracker
 from repro.kernels import ops as kops
-from repro.launch.dist import dist_ctx
+from repro.launch.dist import dist_ctx, place_for_serving
 from repro.models import (attn_logical_capacity, decode_step,
                           decode_step_paged, init_caches, init_paged_caches,
                           prefill, prefill_paged)
@@ -461,6 +461,8 @@ class InferenceEngine:
 
         self.banks = backend.materialize_banks(cfg, params, kv_bytes,
                                                budget=self.budget)
+        if self.dist is not None:
+            place_for_serving(self.dist, self.params, self.banks)
         # ---- fault tolerance (repro.fault) ------------------------------
         # Rebind the transfer plane's clocks to the engine clock (virtual
         # under replay) so promotion ages — the watchdog's input — share

@@ -281,7 +281,7 @@ class HostExpertStore:
         still: List[_PendingLo] = []
         published = 0
         for p in self._staging:
-            ready = wait or all(_is_ready(a) for a in p.arrays)
+            ready = wait or all(a.is_ready() for a in p.arrays)
             if ready and wait:
                 for a in p.arrays:
                     jax.block_until_ready(a)
@@ -346,11 +346,3 @@ class HostExpertStore:
             "quarantined cell counted lo_resident"
         if self._hi_loader is None:
             assert self.hi_present.all()
-
-
-def _is_ready(arr) -> bool:
-    try:
-        return arr.is_ready()
-    except AttributeError:
-        jax.block_until_ready(arr)
-        return True

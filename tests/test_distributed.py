@@ -15,10 +15,11 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np, json
 from repro.configs import get_config
 from repro.launch.dist import DistContext, dist_ctx
+from repro.launch.mesh import make_debug_mesh
 from repro.launch.sharding import ShardingPlanner
 from repro.models import decode_step, init_caches, init_params, prefill
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_debug_mesh(2, 4)
 cfg = get_config("granite-moe-1b-a400m", reduced=True)
 # reduced: E=4 experts over model=4 → 1 expert/rank
 key = jax.random.PRNGKey(0)
@@ -102,7 +103,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, json
 import repro.launch.shapes as shapes
 import repro.launch.dryrun as dr
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 # shrink the shape set for an 8-device smoke of the REAL dry-run machinery
 shapes.SHAPES["tiny_decode"] = dict(kind="decode", seq=128, batch=8)
 shapes.SHAPES["tiny_train"] = dict(kind="train", seq=64, batch=8)

@@ -133,6 +133,27 @@ def test_publish_ready_probes_each_copy_not_the_bank(monkeypatch):
     ctl.tm.check_invariants()
 
 
+def test_promotion_burst_writes_the_pool_in_place():
+    """A burst of promotions keeps one live copy of the hi pool: each slot
+    write donates the leaf the previous one produced (an undonated write
+    allocated a whole pool per promotion and ran a full-width model out of
+    device memory). Donated results still count as landed."""
+    ctl, host, _ = make_controller(n_hi=3)
+    for e in (1, 4, 6):
+        ctl.tm.request_promotion(0, e)
+    ctl.tm.drain()
+    pend = ctl.tm._pending
+    assert len(pend) == 3
+    assert all(a.is_deleted() for p in pend[:-1] for a in p.arrays)
+    assert not any(a.is_deleted() for a in pend[-1].arrays)
+    assert ctl.tm.publish_ready(wait=True) == 3
+    hi = np.asarray(ctl.bank.hi["w"])
+    for e in (1, 4, 6):
+        np.testing.assert_array_equal(hi[0, ctl.tm.slot_map_h[0, e]],
+                                      host["w"][0, e])
+    ctl.tm.check_invariants()
+
+
 def test_demote_while_promoting_reclaims():
     ctl, _, _ = make_controller(n_hi=1)
     a = np.zeros((2, 8), np.int64); a[:, 0] = 100
