@@ -177,12 +177,15 @@ def prompts(vocab: int, lens, seed: int):
             for i, n in enumerate(lens)]
 
 
-def build_engine(cfg, seed: int, argv, dist=None, tune=None):
+def build_engine(cfg, seed: int, argv, dist=None, tune=None,
+                 capacity_factor=None):
     """Engine for ``repro.launch.serve`` arguments ``argv``, built by the
     launcher's own builders over fresh seeded weights (backends take
     ownership of the expert weights, so every engine gets its own).
     ``tune(backend)`` adjusts the backend's policy config before the engine
-    materializes it."""
+    materializes it; ``capacity_factor`` replaces the engine's MoE one."""
+    import dataclasses
+
     import jax
 
     from repro.launch.serve import (build_backend, build_engine_config,
@@ -194,9 +197,11 @@ def build_engine(cfg, seed: int, argv, dist=None, tune=None):
     backend = build_backend(args)
     if tune is not None:
         tune(backend)
+    ecfg = build_engine_config(args)
+    if capacity_factor is not None:
+        ecfg = dataclasses.replace(ecfg, capacity_factor=capacity_factor)
     params = init_params(jax.random.PRNGKey(seed), cfg)
-    return InferenceEngine(cfg, params, backend, build_engine_config(args),
-                           dist=dist)
+    return InferenceEngine(cfg, params, backend, ecfg, dist=dist)
 
 
 def serve_argv(backend: str, batch: int, extra=()) -> list:
@@ -382,9 +387,9 @@ def report_phase(name, cfg, dev, meter, c0, handles, rep, extra="") -> None:
 
 def run_ep(cfg, seed: int, n: int, meter: CompileMeter, dev) -> None:
     """Expert-parallel dynaexq over ``make_ep_mesh(n)`` against the
-    single-device engine on the same weights and prompts, both greedy with
-    the residency policy frozen (no promotion or migration may fire, so
-    both serve the identical all-lo bank)."""
+    single-device engine on the same weights and prompts, both greedy, at
+    drop-free MoE capacity, with the residency policy frozen (no promotion
+    or migration may fire, so both serve the identical all-lo bank)."""
     import jax
     import numpy as np
 
@@ -396,6 +401,11 @@ def run_ep(cfg, seed: int, n: int, meter: CompileMeter, dev) -> None:
     if cfg.moe.num_experts % n:
         fail(f"{cfg.moe.num_experts} experts do not split over {n} chips")
     n_hi = 2 * n
+    # MoE capacity at which no assignment drops: any expert may take every
+    # token. Under a tighter factor EP splits each expert's capacity over
+    # the sending shards (``models.moe.ep_cap_shard``), so which overflow
+    # assignments drop depends on where the tokens sit, by design.
+    drop_free = cfg.moe.num_experts / cfg.moe.top_k
 
     def frozen(backend):
         backend.controller_cfg.update_interval_s = math.inf
@@ -410,7 +420,8 @@ def run_ep(cfg, seed: int, n: int, meter: CompileMeter, dev) -> None:
                  "--ep-shards", str(shards)]
         dist = ep_context(make_ep_mesh(n)) if shards > 1 else None
         eng = build_engine(cfg, seed, serve_argv("dynaexq", len(toks), extra),
-                           dist=dist, tune=frozen)
+                           dist=dist, tune=frozen,
+                           capacity_factor=drop_free)
         handles, rep = serve(eng, toks, keep=EP_DECODE_STEPS)
         report_phase(tag, cfg, dev, meter, c0, handles, rep,
                      f"ep_shards={shards} n_hi={n_hi}")
