@@ -21,10 +21,12 @@ Phases on one chip:
 3. ``static``, ``fp16`` and ``offload``: 2 requests each, speculation off.
 
 Each phase prints one line (widths, device, compile seconds, tokens, TTFT
-and TPOT as the engine reports them, peak device bytes). Any failed check
-exits non-zero. The last line of stdout is a JSON object naming the device.
-Without a TPU, or without the repository's ``src/`` beside it, the script
-exits non-zero before any phase and prints no result.
+and TPOT as the engine reports them, every device's bytes in use before and
+after the engine is built, and device 0's peak after building and after
+serving: a process maximum, never reset). Any failed check exits non-zero.
+The last line of stdout is a JSON object naming the device. Without a TPU,
+or without the repository's ``src/`` beside it, the script exits non-zero
+before any phase and prints no result.
 
 Compiled programs go to JAX's persistent cache: ``JAX_COMPILATION_CACHE_DIR``
 when set, else ``<checkout>/.jax_cache``.
@@ -32,6 +34,8 @@ when set, else ``<checkout>/.jax_cache``.
 from __future__ import annotations
 
 import argparse
+import collections
+import dataclasses
 import functools
 import gc
 import json
@@ -57,24 +61,29 @@ PROMPT_LENS = (100, 700)        # prefill buckets 128 and 740 (= max_len)
 #: a few such flips landing on the same output and nothing more.
 KERNEL_TOL = 2e-2
 
-#: EP vs single device, one MoE layer on the same input rows: the largest
-#: per-token relative L2 error of the layer output. Each token meets the
-#: same experts' weights through the same kernel on both; what may differ
-#: is which rows share a kernel tile, the shape of the router matmul and
-#: the order of the gate-weighted bf16 sums, a few bf16 roundings (2^-8
-#: each). A token whose router top-8 holds a near tie may resolve it the
-#: other way and take another expert, so up to EP_LAYER_FLIP_SHARE of the
-#: tokens may exceed the bound. Layer inputs: one decode step of 4 rows
-#: and one 2048-row prefill chunk (both divide over the token shards).
-EP_LAYER_TOL = 2e-2
-EP_LAYER_FLIP_SHARE = 1e-2
-EP_LAYER_ROWS = (4, 2048)
-#: The whole-model logits and greedy tokens of the two engines are
-#: reported, not bounded: with random weights the logits are nearly flat,
-#: and 24 layers of top-8 routing turn those roundings into other expert
-#: choices, other hidden states and other argmaxes (on CPU, 24 layers at
-#: small widths already move a decode logit by 15% of the logit scale).
-EP_DECODE_STEPS = 4
+#: EP vs single device. Both engines serve the same prompts greedily from
+#: the same weights at drop-free MoE capacity with the residency policy
+#: frozen, so request i's logits row j has identical inputs on both while
+#: the two emitted the same first j tokens; every such row is compared
+#: (relative L2 error). The EP step routes each token through the same
+#: experts and kernel, but other rows share its kernel tiles and its gate-
+#: weighted sum is formed in another order: a few bf16 roundings (2^-8
+#: each) of the MoE output. At depth 1 (the published widths, one layer)
+#: that moves a logits row by well under EP_TOL. A token whose router
+#: top-8 holds a near tie may take another expert when its router input
+#: rounds differently; its row then moves by about one expert's share of
+#: the output, so up to EP_FLIP_SHARE of the rows may exceed EP_TOL.
+EP_TOL = 2e-2
+EP_FLIP_SHARE = 0.05
+#: Both depths also run a control: the single-device engine against itself
+#: with layer 0's MoE input-norm scale moved by one bf16 ulp (1 → 1 +
+#: 2^-7), a change of the size of one rounding. At the published depth of
+#: 24 layers the random-weight model turns such roundings into other expert
+#: choices in later layers and other argmaxes, so there the bound is
+#: relative to the control: the EP engine's median row error may be at
+#: most EP_CTRL_FACTOR times the control's. A wrong expert mapping or a
+#: lost shard gives an unrelated row, a relative error near sqrt(2).
+EP_CTRL_FACTOR = 2.0
 
 
 def fail(msg: str) -> None:
@@ -83,7 +92,7 @@ def fail(msg: str) -> None:
 
 
 # ---------------------------------------------------------------------------
-# Instrumentation: compile time and logits, observed from outside the engine
+# Instrumentation: compile time, logits and device memory
 # ---------------------------------------------------------------------------
 
 class CompileMeter:
@@ -109,30 +118,43 @@ class CompileMeter:
         return self.seconds, self.hits
 
 
-class LogitWatch:
-    """Wraps a jitted forward that returns ``(logits, ...)``: keeps one
-    on-device all-finite flag per call and the host copy of the logits of
-    the first ``keep`` calls."""
+class LogitLog:
+    """An ``InferenceEngine.logits_hook``: counts forwards by kind, keeps
+    one on-device all-finite flag per forward and, with ``keep``, the host
+    copy of the logits row each request drew its j-th token from, keyed by
+    (request index, j)."""
 
-    def __init__(self, fn, keep: int = 0):
-        import jax.numpy as jnp
-        self._jnp = jnp
-        self.fn = fn
+    def __init__(self, keep: bool = False):
         self.keep = keep
+        self.index = {}                  # RequestHandle.id → request index
+        self.forwards = collections.Counter()
         self.flags = []
-        self.kept = []
+        self.rows = {}
 
-    def __call__(self, *a, **kw):
-        out = self.fn(*a, **kw)
-        logits = out[0]
-        self.flags.append(self._jnp.isfinite(logits).all())
-        if len(self.kept) < self.keep:
-            import numpy as np
-            self.kept.append(np.asarray(logits, np.float32))
-        return out
+    def __call__(self, kind, logits, rows):
+        import jax.numpy as jnp
+        import numpy as np
+        self.forwards[kind] += 1
+        self.flags.append(jnp.isfinite(logits).all())
+        if self.keep and kind != "verify":
+            host = np.asarray(logits, np.float32)
+            for r, h in rows:
+                self.rows[(self.index[h.id], len(h.tokens))] = host[r]
 
     def all_finite(self) -> bool:
         return all(bool(f) for f in self.flags)
+
+
+def device_bytes() -> list:
+    """``bytes_in_use`` of every device, in device order."""
+    import jax
+    return [int((d.memory_stats() or {}).get("bytes_in_use", -1))
+            for d in jax.devices()]
+
+
+def peak_bytes(dev) -> int:
+    stats = dev.memory_stats() or {}
+    return int(stats.get("peak_bytes_in_use", -1))
 
 
 # ---------------------------------------------------------------------------
@@ -189,14 +211,14 @@ def prompts(vocab: int, lens, seed: int):
 
 
 def build_engine(cfg, seed: int, argv, dist=None, tune=None,
-                 capacity_factor=None):
+                 capacity_factor=None, nudge=None):
     """Engine for ``repro.launch.serve`` arguments ``argv``, built by the
     launcher's own builders over fresh seeded weights (backends take
     ownership of the expert weights, so every engine gets its own).
     ``tune(backend)`` adjusts the backend's policy config before the engine
-    materializes it; ``capacity_factor`` replaces the engine's MoE one."""
-    import dataclasses
-
+    materializes it; ``capacity_factor`` replaces the engine's MoE one;
+    ``nudge(params)`` edits the weights in place before the engine sees
+    them."""
     import jax
 
     from repro.launch.serve import (build_backend, build_engine_config,
@@ -212,6 +234,8 @@ def build_engine(cfg, seed: int, argv, dist=None, tune=None,
     if capacity_factor is not None:
         ecfg = dataclasses.replace(ecfg, capacity_factor=capacity_factor)
     params = init_params(jax.random.PRNGKey(seed), cfg)
+    if nudge is not None:
+        nudge(params)
     return InferenceEngine(cfg, params, backend, ecfg, dist=dist)
 
 
@@ -221,73 +245,28 @@ def serve_argv(backend: str, batch: int, extra=()) -> list:
             "--new-tokens", str(NEW_TOKENS), *extra]
 
 
-def serve(engine, toks, watch_spec: bool = False, keep: int = 0):
+def serve(engine, toks, keep: bool = False):
     """Submit every prompt, drain, and report. Returns (handles, report);
-    ``report["logits"]`` holds the host logits of the first ``keep``
-    prefill and decode forwards."""
-    import repro.serving.spec as spec_mod
+    with ``keep``, ``report["rows"]`` holds the host logits row behind each
+    emitted token (see ``LogitLog``)."""
     from repro.serving import Request
 
-    watches = {"prefill": LogitWatch(engine._jit_prefill_paged, keep),
-               "decode": LogitWatch(engine._jit_decode_paged, keep)}
-    engine._jit_prefill_paged = watches["prefill"]
-    engine._jit_decode_paged = watches["decode"]
-    verify = spec_mod._verify_paged_jit
-    if watch_spec:
-        watches["verify"] = spec_mod._verify_paged_jit = LogitWatch(verify)
-    try:
-        t0 = time.perf_counter()
-        handles = [engine.submit(Request(tokens=t, max_new_tokens=NEW_TOKENS))
-                   for t in toks]
-        engine.drain()
-        wall = time.perf_counter() - t0
-    finally:
-        spec_mod._verify_paged_jit = verify
+    log = LogitLog(keep)
+    engine.logits_hook = log
+    t0 = time.perf_counter()
+    handles = []
+    for i, t in enumerate(toks):
+        h = engine.submit(Request(tokens=t, max_new_tokens=NEW_TOKENS))
+        log.index[h.id] = i
+        handles.append(h)
+    engine.drain()
+    wall = time.perf_counter() - t0
+    engine.logits_hook = None
     st = engine.stats()           # before flush: promotions published mid-run
     engine.flush()
-    return handles, {
-        "wall_s": wall, "stats": st,
-        "forwards": {k: len(w.flags) for k, w in watches.items()},
-        "finite": all(w.all_finite() for w in watches.values()),
-        "logits": {k: watches[k].kept for k in ("prefill", "decode")}}
-
-
-def moe_layer_out(engine, x):
-    """Output of the engine's first MoE layer (layer 0 of the scanned
-    stack) on rows ``x`` (T, d): its own router, bank and dispatch, at its
-    capacity factor, under its mesh when it has one."""
-    import jax
-    import numpy as np
-
-    from repro.launch.dist import dist_ctx
-    from repro.models import moe as X
-
-    pos = next(iter(engine.banks))
-    first = functools.partial(jax.tree_util.tree_map, lambda a: a[0])
-    params = first(engine.params["blocks"][pos]["moe"])
-    bank = first(engine.banks[pos])
-    moe_cfg = engine.cfg.moe
-    cap = X.moe_capacity(x.shape[0], moe_cfg, engine.ecfg.capacity_factor)
-    fn = jax.jit(lambda p, b, x: X.moe_apply(
-        p, b, x, moe_cfg, cap, dispatch=engine.moe_dispatch)[0])
-    with dist_ctx(engine.dist):
-        return np.asarray(fn(params, bank, x), np.float32)
-
-
-def decode_program_text(engine) -> str:
-    """StableHLO of the engine's paged decode step, lowered for its current
-    state (the program every non-speculative decode step runs)."""
-    import jax.numpy as jnp
-
-    fn = engine._jit_decode_paged
-    fn = getattr(fn, "fn", fn)                  # unwrap a LogitWatch
-    n = engine.ecfg.max_slots
-    zeros = jnp.zeros(n, jnp.int32)
-    return fn.func.lower(
-        engine.params, jnp.asarray(engine.tokens), jnp.asarray(engine.pos),
-        engine.caches, engine.banks, jnp.zeros(n, bool),
-        jnp.asarray(engine._block_tables()), zeros, zeros,
-        **fn.keywords).as_text()
+    return handles, {"wall_s": wall, "stats": st,
+                     "forwards": dict(log.forwards),
+                     "finite": log.all_finite(), "rows": log.rows}
 
 
 def widths(cfg) -> str:
@@ -295,11 +274,6 @@ def widths(cfg) -> str:
     return (f"{cfg.name} L={cfg.n_layers} d={cfg.d_model} E={m.num_experts} "
             f"top{m.top_k} F={m.d_ff_expert} heads={a.n_heads}/{a.n_kv_heads}"
             f"x{a.head_dim} V={cfg.vocab_size}")
-
-
-def peak_bytes(dev) -> int:
-    stats = dev.memory_stats() or {}
-    return int(stats.get("peak_bytes_in_use", -1))
 
 
 def check_served(name: str, handles, rep) -> None:
@@ -353,6 +327,30 @@ def main(argv=None) -> None:
         "count": jax.device_count()}}))
 
 
+def run_phase(name, cfg, seed, argv, toks, meter, dev, keep=False, **kw):
+    """Build one engine, serve ``toks`` through it, print the phase line.
+    Returns (engine, handles, report)."""
+    c0 = meter.snapshot()
+    before = device_bytes()
+    eng = build_engine(cfg, seed, argv, **kw)
+    built, peak_built = device_bytes(), peak_bytes(dev)
+    handles, rep = serve(eng, toks, keep=keep)
+    st = rep["stats"]
+    print(f"[{name}] {widths(cfg)} | {dev.device_kind} | compile "
+          f"{meter.seconds - c0[0]:.2f} s ({meter.hits - c0[1]} cache hits) | "
+          f"tokens {sum(len(h.tokens) for h in handles)} in "
+          f"{rep['wall_s']:.2f} s | ttft {st.get('ttft_s', 0.0):.6f} s "
+          f"tpot {st.get('tpot_s', 0.0):.6f} s | forwards {rep['forwards']} "
+          f"| dispatch={eng.moe_dispatch} promotions "
+          f"{st['promotions']:.0f} | bytes in use before {before} built "
+          f"{built} | peak after build {peak_built} after serving "
+          f"{peak_bytes(dev)} B")
+    check_served(name, handles, rep)
+    if eng.moe_dispatch != "ragged":
+        fail(f"{name}: moe_dispatch={eng.moe_dispatch}, expected ragged")
+    return eng, handles, rep
+
+
 def run_one_chip(cfg, seed: int, meter: CompileMeter, dev) -> None:
     # 1 — kernel against oracle
     c0 = meter.snapshot()
@@ -374,57 +372,64 @@ def run_one_chip(cfg, seed: int, meter: CompileMeter, dev) -> None:
         backend.controller_cfg.update_interval_s = 0.0
 
     toks = prompts(cfg.vocab_size, PROMPT_LENS * 2, seed)
-    c0 = meter.snapshot()
-    eng = build_engine(cfg, seed, serve_argv("dynaexq", 4, ["--n-hi", "4"]),
-                       tune=short_window)
-    handles, rep = serve(eng, toks, watch_spec=True)
-    st = rep["stats"]
-    hlo = decode_program_text(eng)
-    report_phase("dynaexq", cfg, dev, meter, c0, handles, rep,
-                 f"spec_k={eng.ecfg.spec_k} promotions "
-                 f"{st['promotions']:.0f} dispatch={eng.moe_dispatch} "
-                 f"decode tpu_custom_call={'tpu_custom_call' in hlo}")
-    check_served("dynaexq", handles, rep)
-    if st["promotions"] < 1:
+    eng, handles, rep = run_phase(
+        "dynaexq", cfg, seed, serve_argv("dynaexq", 4, ["--n-hi", "4"]),
+        toks, meter, dev, tune=short_window)
+    custom = "tpu_custom_call" in eng.decode_program_text()
+    print(f"[dynaexq] spec_k={eng.ecfg.spec_k} decode step "
+          f"tpu_custom_call={custom}")
+    if rep["stats"]["promotions"] < 1:
         fail("dynaexq: no promotion published while serving")
-    if eng.moe_dispatch != "ragged":
-        fail(f"dynaexq: moe_dispatch={eng.moe_dispatch}, expected ragged")
-    if "tpu_custom_call" not in hlo:
+    if not custom:
         fail("dynaexq: the decode step holds no Pallas kernel")
     del eng, handles
     gc.collect()
 
     # 3 — the baselines, speculation off
     for name in ("static", "fp16", "offload"):
-        c0 = meter.snapshot()
-        eng = build_engine(cfg, seed, serve_argv(name, 2, ["--no-spec"]))
-        handles, rep = serve(eng, toks[:2])
-        report_phase(name, cfg, dev, meter, c0, handles, rep,
-                     f"dispatch={eng.moe_dispatch}")
-        check_served(name, handles, rep)
-        if eng.moe_dispatch != "ragged":
-            fail(f"{name}: moe_dispatch={eng.moe_dispatch}, expected ragged")
+        eng, handles, _ = run_phase(name, cfg, seed,
+                                    serve_argv(name, 2, ["--no-spec"]),
+                                    toks[:2], meter, dev)
         del eng, handles
         gc.collect()
 
 
-def report_phase(name, cfg, dev, meter, c0, handles, rep, extra="") -> None:
-    st = rep["stats"]
-    print(f"[{name}] {widths(cfg)} | {dev.device_kind} | compile "
-          f"{meter.seconds - c0[0]:.2f} s ({meter.hits - c0[1]} cache hits) | "
-          f"tokens {sum(len(h.tokens) for h in handles)} in "
-          f"{rep['wall_s']:.2f} s | ttft {st.get('ttft_s', 0.0):.6f} s "
-          f"tpot {st.get('tpot_s', 0.0):.6f} s | forwards {rep['forwards']} "
-          f"| {extra} | peak {peak_bytes(dev)} B")
+def nudge_ulp(params) -> None:
+    """The control's perturbation: layer 0's MoE input-norm scale moved up
+    by one bf16 ulp."""
+    import jax.numpy as jnp
+    norm = params["blocks"]["0"]["norm2"]
+    s = norm["scale"]
+    norm["scale"] = s.at[0].set(jnp.nextafter(s[0], jnp.asarray(jnp.inf,
+                                                                s.dtype)))
+
+
+def compare(ref, got):
+    """Relative L2 errors of the logits rows whose inputs agree (request
+    i's row j while both emitted the same first j tokens), and the number
+    of equal tokens. ``ref``/``got``: (token lists, ``LogitLog.rows``)."""
+    import numpy as np
+
+    (t_ref, rows_ref), (t_got, rows_got) = ref, got
+    errs = []
+    for i, (a, b) in enumerate(zip(t_ref, t_got)):
+        for j in range(len(a)):
+            if a[:j] != b[:j]:
+                break
+            x, y = rows_ref[(i, j)], rows_got[(i, j)]
+            errs.append(float(np.linalg.norm(y - x) / np.linalg.norm(x)))
+    match = sum(int(x == y) for a, b in zip(t_ref, t_got)
+                for x, y in zip(a, b))
+    return np.asarray(errs), match
 
 
 def run_ep(cfg, seed: int, n: int, meter: CompileMeter, dev) -> None:
     """Expert-parallel dynaexq over ``make_ep_mesh(n)`` against the
     single-device engine on the same weights and prompts, both greedy, at
     drop-free MoE capacity, with the residency policy frozen (no promotion
-    or migration may fire, so both serve the identical all-lo bank)."""
+    or migration may fire, so both serve the identical all-lo bank). Runs
+    at depth 1 and at the published depth; see EP_TOL and EP_CTRL_FACTOR."""
     import jax
-    import jax.numpy as jnp
     import numpy as np
 
     from repro.launch.dist import ep_context
@@ -447,69 +452,47 @@ def run_ep(cfg, seed: int, n: int, meter: CompileMeter, dev) -> None:
             backend.coordinator.cfg.interval_s = math.inf
 
     toks = prompts(cfg.vocab_size, PROMPT_LENS * 2, seed)
-    key = jax.random.PRNGKey(seed + 1)
-    layer_in = [jax.random.normal(k, (rows, cfg.d_model), jnp.bfloat16)
-                for k, rows in zip(jax.random.split(key, len(EP_LAYER_ROWS)),
-                                   EP_LAYER_ROWS)]
-    runs, layer = {}, {}
-    for tag, shards in (("single", 1), (f"ep{n}", n)):
-        c0 = meter.snapshot()
-        extra = ["--no-spec", "--n-hi", str(n_hi), "--moe-dispatch", "ragged",
-                 "--ep-shards", str(shards)]
-        dist = ep_context(make_ep_mesh(n)) if shards > 1 else None
-        eng = build_engine(cfg, seed, serve_argv("dynaexq", len(toks), extra),
-                           dist=dist, tune=frozen,
-                           capacity_factor=drop_free)
-        handles, rep = serve(eng, toks, keep=EP_DECODE_STEPS)
-        report_phase(tag, cfg, dev, meter, c0, handles, rep,
-                     f"ep_shards={shards} n_hi={n_hi}")
-        check_served(tag, handles, rep)
-        if rep["stats"]["promotions"] or rep["stats"].get("migrations", 0):
-            fail(f"{tag}: the frozen policy moved experts")
-        runs[tag] = ([h.tokens for h in handles],
-                     (rep["logits"]["prefill"], rep["logits"]["decode"]))
-        layer[tag] = [moe_layer_out(eng, x) for x in layer_in]
-        del eng, handles
-        gc.collect()
+    ep = f"ep{n}"
+    for depth in (1, cfg.n_layers):
+        c = dataclasses.replace(cfg, n_layers=depth)
+        runs = {}
+        for tag, shards, nudge in (("single", 1, None), (ep, n, None),
+                                   ("single+ulp", 1, nudge_ulp)):
+            extra = ["--no-spec", "--n-hi", str(n_hi), "--moe-dispatch",
+                     "ragged", "--ep-shards", str(shards)]
+            dist = ep_context(make_ep_mesh(n)) if shards > 1 else None
+            eng, handles, rep = run_phase(
+                f"{tag} L={depth}", c, seed,
+                serve_argv("dynaexq", len(toks), extra), toks, meter, dev,
+                keep=True, dist=dist, tune=frozen,
+                capacity_factor=drop_free, nudge=nudge)
+            if rep["stats"]["promotions"] or rep["stats"].get("migrations", 0):
+                fail(f"{tag}: the frozen policy moved experts")
+            runs[tag] = ([list(h.tokens) for h in handles], rep["rows"])
+            del eng, handles, rep
+            gc.collect()
 
-    for rows, ref, got in zip(EP_LAYER_ROWS, layer["single"],
-                              layer[f"ep{n}"]):
-        err = (np.linalg.norm(got - ref, axis=1)
-               / np.maximum(np.linalg.norm(ref, axis=1), 1e-30))
-        over = float(np.mean(err > EP_LAYER_TOL))
-        print(f"[ep{n}-vs-single] MoE layer 0, {rows} rows: per-token "
-              f"relative error median {np.median(err):.6g} max "
-              f"{err.max():.6g}; share over {EP_LAYER_TOL}: {over:.6g} "
-              f"(allowed {EP_LAYER_FLIP_SHARE})")
-        if not np.isfinite(got).all() or over > EP_LAYER_FLIP_SHARE:
-            fail(f"EP MoE layer output differs from the single-device one "
-                 f"on {rows} rows")
-
-    (t_ref, (pre_ref, dec_ref)), (t_ep, (pre_ep, dec_ep)) = \
-        runs["single"], runs[f"ep{n}"]
-    if len(pre_ref) != len(pre_ep) or len(dec_ref) != len(dec_ep):
-        fail("EP and single-device engines ran different forward counts")
-    scale = max(float(np.abs(x).max()) for x in pre_ref + dec_ref)
-    pre_err = max(float(np.abs(a - b).max()) for a, b in zip(pre_ref, pre_ep))
-    pre_rel = max(float(np.max(np.linalg.norm(a - b, axis=-1)
-                               / np.linalg.norm(a, axis=-1)))
-                  for a, b in zip(pre_ref, pre_ep))
-    # Decode steps compare while every row still holds the same tokens.
-    agree = 0
-    for j in range(len(dec_ref)):
-        if any(a[:j + 1] != b[:j + 1] for a, b in zip(t_ref, t_ep)):
-            break
-        agree += 1
-    dec_err = max((float(np.abs(a - b).max())
-                   for a, b in zip(dec_ref[:agree], dec_ep[:agree])),
-                  default=0.0)
-    match = sum(int(x == y) for a, b in zip(t_ref, t_ep)
-                for x, y in zip(a, b))
-    total = sum(len(a) for a in t_ref)
-    print(f"[ep{n}-vs-single] whole model: prefill max|dlogit| {pre_err:.6g} "
-          f"(logit scale {scale:.6g}), largest per-row relative error "
-          f"{pre_rel:.6g}; decode max|dlogit| {dec_err:.6g} over {agree} "
-          f"steps | tokens match {match}/{total}")
+        total = sum(len(t) for t in runs["single"][0])
+        errs, match = compare(runs["single"], runs[ep])
+        over = float(np.mean(errs > EP_TOL))
+        print(f"[{ep}-vs-single L={depth}] {errs.size} logits rows with "
+              f"equal inputs (prefill and decode): relative error median "
+              f"{np.median(errs):.6g} max {errs.max():.6g}; share over "
+              f"{EP_TOL}: {over:.6g} | tokens match {match}/{total}")
+        ctrl, ctrl_match = compare(runs["single"], runs["single+ulp"])
+        print(f"[single+ulp-vs-single L={depth}] {ctrl.size} logits rows "
+              f"with equal inputs: relative error median "
+              f"{np.median(ctrl):.6g} max {ctrl.max():.6g} | tokens match "
+              f"{ctrl_match}/{total}")
+        if not np.isfinite(errs).all():
+            fail(f"L={depth}: non-finite logits difference")
+        if depth == 1:
+            if over > EP_FLIP_SHARE:
+                fail(f"L=1: {over:.3g} of the EP logits rows differ from "
+                     f"the single-device ones by more than {EP_TOL}")
+        elif np.median(errs) > EP_CTRL_FACTOR * np.median(ctrl):
+            fail(f"L={depth}: EP logits differ from the single-device ones "
+                 f"by more than {EP_CTRL_FACTOR}x a one-ulp perturbation")
 
 
 if __name__ == "__main__":

@@ -9,8 +9,10 @@ Queues + admission control + publish-then-switch:
   per-window migration-rate limit (bounded interference).
 * An admitted promotion allocates a slot from the layer's ``SlotPool`` and
   issues the hi-weight copy (``write_hi_slot``). JAX dispatch is async — this
-  is the migration-stream analogue: the copy is independent of the in-flight
-  serve step because the slot is unpublished.
+  is the migration-stream analogue: the host goes on serving while the copy
+  lands, and no forward reads the slot because it is unpublished. The copy
+  writes the pool in place (donation), so on the device it is ordered after
+  the forwards already dispatched against the pool.
 * ``publish_ready()`` — called at a window boundary — publishes completed
   copies by writing ``slot_map``/``slot_owner``. A copy is "complete" when
   its result array is ready (the CUDA-event analogue).
@@ -220,13 +222,10 @@ class TransitionManager:
             ensure = getattr(self.host_hi, "ensure_hi", None)
             if ensure is not None:
                 ensure(layer, expert)
-            # Every host read before the first write: the writes donate the
-            # bank's leaves, so nothing may fail between them.
             rows = {name: jnp.asarray(self.host_hi[name][layer, expert])
-                    .astype(leaf.dtype) for name, leaf in self.bank.hi.items()}
-            return {name: write_hi_slot(leaf, jnp.int32(layer),
-                                        jnp.int32(slot), rows[name])
-                    for name, leaf in self.bank.hi.items()}
+                    for name in self.bank.hi}
+            return write_hi_slot(self.bank.hi, jnp.int32(layer),
+                                 jnp.int32(slot), rows)
 
         seed = self.injector.seed if self.injector is not None else 0
         try:

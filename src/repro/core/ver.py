@@ -176,19 +176,24 @@ def expert_lo_nbytes(expert_weights_shapes: Dict[str, tuple], lo_bits: int,
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.jit, donate_argnums=0)
-def write_hi_slot(hi_leaf: jax.Array, layer: jax.Array, slot: jax.Array,
-                  w: jax.Array) -> jax.Array:
-    """Copy one expert's hi weights into pool slot (layer, slot), in place:
-    ``hi_leaf`` is donated, so a burst of promotions never holds more than
-    one copy of the pool (an undonated write allocates the whole (L, slots,
-    K, N) leaf anew per promotion).
+def write_hi_slot(hi: Dict[str, jax.Array], layer: jax.Array,
+                  slot: jax.Array, w: Dict[str, jax.Array]
+                  ) -> Dict[str, jax.Array]:
+    """Copy one expert's hi weights (name → (K, N)) into pool slot (layer,
+    slot) of every leaf of ``hi`` (name → (L, slots, K, N)), in place.
 
-    This is the 'async copy on stream_mig': the serve step in flight does not
-    depend on this buffer (the slot is unpublished), so XLA is free to overlap
-    it with compute.
+    The leaves are donated together, in one program: a burst of promotions
+    never holds more than one copy of the pool (an undonated write allocates
+    each whole leaf anew per promotion), and a write that fails consumes
+    none of them. Donation orders the write after every dispatched forward
+    that reads the pool, so the copy does not overlap the serve step in
+    flight; it still runs asynchronously to the host, which dispatches the
+    next step without waiting for it.
     """
-    return jax.lax.dynamic_update_slice(
-        hi_leaf, w[None, None], (layer, slot) + (0,) * (w.ndim))
+    return {n: jax.lax.dynamic_update_slice(
+                leaf, w[n].astype(leaf.dtype)[None, None],
+                (layer, slot) + (0,) * w[n].ndim)
+            for n, leaf in hi.items()}
 
 
 @jax.jit

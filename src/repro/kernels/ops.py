@@ -158,23 +158,23 @@ def ragged_dense_ffn_op(xs: jax.Array, tile_eid: jax.Array, bank: dict,
                                      bm=bm)
 
 
-@partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
+@partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
 def quant_matmul_op(x: jax.Array, qt: QuantizedTensor, bm: int = 128,
-                    bn: int = 128, bk: int = 256,
+                    bn: int = 128,
                     interpret: bool | None = None) -> jax.Array:
     interpret = _interpret_default() if interpret is None else interpret
     return quant_matmul(x, qt.packed, qt.scales, bits=qt.bits,
-                        group=qt.group_size, bm=bm, bn=bn, bk=bk,
+                        group=qt.group_size, bm=bm, bn=bn,
                         interpret=interpret)
 
 
-@partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
+@partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
 def grouped_quant_matmul_op(xg: jax.Array, qt: QuantizedTensor, bm: int = 128,
-                            bn: int = 128, bk: int = 256,
+                            bn: int = 128,
                             interpret: bool | None = None) -> jax.Array:
     interpret = _interpret_default() if interpret is None else interpret
     return grouped_quant_matmul(xg, qt.packed, qt.scales, bits=qt.bits,
-                                group=qt.group_size, bm=bm, bn=bn, bk=bk,
+                                group=qt.group_size, bm=bm, bn=bn,
                                 interpret=interpret)
 
 

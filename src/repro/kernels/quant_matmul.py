@@ -26,10 +26,10 @@ Three kernels:
   grouped w_down GEMM rides the same tile maps.
 
 Tiling: grid (tiles, N/bn, K/bk); K is the innermost (sequential) axis with
-f32 VMEM accumulators. bk is a whole number of quantization groups, chosen
-by ``_k_tile`` so that the scales block either spans a multiple of 16 rows
-or the whole K/group axis — the TPU lowering refuses anything else for a
-bf16 block (at group 64 and K ≤ 1024, one K step covers the contraction).
+f32 VMEM accumulators. The contraction tile bk is ``_k_tile``'s: 16 scale
+groups, or the whole K when that does not divide it — a bf16 scales block
+must span a multiple of 16 rows or the whole K/group axis, or the TPU
+lowering refuses it (at group 64 and K ≤ 1024, one K step covers K).
 """
 from __future__ import annotations
 
@@ -46,13 +46,11 @@ from jax.experimental import pallas as pl
 _SCALE_ROWS = 16
 
 
-def _k_tile(K: int, group: int, pref: int) -> int:
-    """Contraction tile: the largest multiple of ``_SCALE_ROWS`` scale
-    groups not above ``pref`` (at least one such multiple) when it divides
+def _k_tile(K: int, group: int) -> int:
+    """Contraction tile: ``_SCALE_ROWS`` scale groups when that divides
     ``K``, else the whole of ``K``. Either way each K tile sees whole scale
     groups and its scales block is one the TPU lowering accepts."""
-    step = _SCALE_ROWS * group
-    bk = max(step, pref // step * step)
+    bk = _SCALE_ROWS * group
     return bk if K % bk == 0 else K
 
 
@@ -112,12 +110,12 @@ def _qmm_kernel(x_ref, wp_ref, s_ref, o_ref, acc_ref, *, bits, group, nk):
 
 def quant_matmul(x: jax.Array, packed: jax.Array, scales: jax.Array, *,
                  bits: int, group: int, bm: int = 128, bn: int = 128,
-                 bk: int = 256, interpret: bool = False) -> jax.Array:
+                 interpret: bool = False) -> jax.Array:
     """x: (M, K) bf16 × packed (K//epb, N) uint8 / scales (K//g, N) → (M, N)."""
     M, K = x.shape
     epb = 8 // bits
     N = packed.shape[1]
-    bm, bn, bk = min(bm, M), min(bn, N), _k_tile(K, group, bk)
+    bm, bn, bk = min(bm, M), min(bn, N), _k_tile(K, group)
     if M % bm or N % bn or K % bk:
         raise ValueError(f"shape ({M},{K})x({K},{N}) not tileable by "
                          f"({bm},{bn},{bk})")
@@ -160,13 +158,13 @@ def _gqmm_kernel(x_ref, wp_ref, s_ref, o_ref, acc_ref, *, bits, group, nk):
 
 def grouped_quant_matmul(xg: jax.Array, packed: jax.Array, scales: jax.Array,
                          *, bits: int, group: int, bm: int = 128,
-                         bn: int = 128, bk: int = 256,
+                         bn: int = 128,
                          interpret: bool = False) -> jax.Array:
     """xg: (E, C, K) × packed (E, K//epb, N) → (E, C, N)."""
     E, C, K = xg.shape
     epb = 8 // bits
     N = packed.shape[2]
-    bm, bn, bk = min(bm, C), min(bn, N), _k_tile(K, group, bk)
+    bm, bn, bk = min(bm, C), min(bn, N), _k_tile(K, group)
     if C % bm or N % bn or K % bk:
         raise ValueError(f"({E},{C},{K})x({K},{N}) not tileable by "
                          f"({bm},{bn},{bk})")
@@ -293,8 +291,7 @@ def ragged_quant_ffn(xs: jax.Array, tile_lo: jax.Array, tile_hi: jax.Array,
                      gate_packed, gate_scales, up_packed, up_scales,
                      down_packed, down_scales,
                      hi_gate=None, hi_up=None, hi_down=None, *,
-                     bits: int, group: int, bm: int,
-                     bn: int = 128, bk: int = 256,
+                     bits: int, group: int, bm: int, bn: int = 128,
                      interpret: bool = False) -> jax.Array:
     """One fused mixed-precision SwiGLU FFN over the ragged token layout.
 
@@ -321,8 +318,8 @@ def ragged_quant_ffn(xs: jax.Array, tile_lo: jax.Array, tile_hi: jax.Array,
     has_hi = hi_gate is not None and hi_gate.shape[0] > 0
     bn_f = _fit_tile(F, bn)
     bn_d = _fit_tile(D, bn)
-    bk_k = _k_tile(K, group, bk)
-    bk_f = _k_tile(F, group, bk)
+    bk_k = _k_tile(K, group)
+    bk_f = _k_tile(F, group)
     if K % bk_k or K % group or F % bn_f or F % bk_f or F % group or D % bn_d:
         raise ValueError(f"(K={K}, F={F}, D={D}) not tileable by "
                          f"(bk={bk_k}/{bk_f}, bn={bn_f}/{bn_d}, g={group})")

@@ -507,24 +507,28 @@ class InferenceEngine:
             1, cfg.moe, self.ecfg.capacity_factor) if norm else None
         self._row_cap_norm = norm
         ep_key = 0 if self.dist is None else self.dist.n_token_shards
+        self._decode_kw = dict(cfg=cfg,
+                               capacity_factor=self.ecfg.capacity_factor,
+                               moe_dispatch=self.moe_dispatch,
+                               row_capacity=self._row_cap_decode, ep=ep_key)
         self._jit_prefill = self._dist_wrap(functools.partial(
             _prefill_jit, cfg=cfg,
             capacity_factor=self.ecfg.capacity_factor,
             moe_dispatch=self.moe_dispatch, ep=ep_key))
         self._jit_decode = self._dist_wrap(functools.partial(
-            _decode_jit, cfg=cfg,
-            capacity_factor=self.ecfg.capacity_factor,
-            moe_dispatch=self.moe_dispatch,
-            row_capacity=self._row_cap_decode, ep=ep_key))
+            _decode_jit, **self._decode_kw))
         self._jit_prefill_paged = self._dist_wrap(functools.partial(
             _prefill_paged_jit, cfg=cfg,
             capacity_factor=self.ecfg.capacity_factor,
             moe_dispatch=self.moe_dispatch, ep=ep_key))
         self._jit_decode_paged = self._dist_wrap(functools.partial(
-            _decode_paged_jit, cfg=cfg,
-            capacity_factor=self.ecfg.capacity_factor,
-            moe_dispatch=self.moe_dispatch,
-            row_capacity=self._row_cap_decode, ep=ep_key))
+            _decode_paged_jit, **self._decode_kw))
+        # Optional observer of every forward's logits, called as
+        # ``logits_hook(kind, logits, rows)``: kind "prefill", "decode" or
+        # "verify", the device logits, and the (row, handle) pairs the
+        # forward served — row indexes the logits' batch dim (the second
+        # dim of verify's (k+1, B, V)). None costs one check per forward.
+        self.logits_hook = None
         self._jit_scatter = _scatter_rows
         # Dispatch-efficiency gauges (host mirror of MoEAux telemetry).
         self._disp_active_sum = 0.0
@@ -639,6 +643,21 @@ class InferenceEngine:
             with dist_ctx(self.dist):
                 return fn(*a, **kw)
         return wrapped
+
+    def decode_program_text(self) -> str:
+        """StableHLO text of the single-token decode program this engine
+        runs, lowered for its current state with every row vacant — where
+        a check looks for the kernels the step holds."""
+        n = self.ecfg.max_slots
+        args = [self.params, jnp.asarray(self.tokens), jnp.asarray(self.pos),
+                self.caches, self.banks, jnp.zeros(n, bool)]
+        fn = _decode_jit
+        if self.pool is not None:
+            zeros = jnp.zeros(n, jnp.int32)
+            args += [jnp.asarray(self._block_tables()), zeros, zeros]
+            fn = _decode_paged_jit
+        with dist_ctx(self.dist):
+            return fn.lower(*args, **self._decode_kw).as_text()
 
     # ------------------------------------------------------------------
     def _row_cap_prefill(self, bucket: int) -> Optional[int]:
@@ -1147,6 +1166,8 @@ class InferenceEngine:
         the prefix-share saving shows up here)."""
         R = self._prefill_rows
         G = len(group)
+        if self.logits_hook is not None:
+            self.logits_hook("prefill", logits, list(enumerate(group)))
         amax = np.asarray(jnp.argmax(logits, -1), np.int32)
         samp = self._gather_sampling_rows(
             logits, [r for r, h in enumerate(group)
@@ -1573,6 +1594,9 @@ class InferenceEngine:
             h.stall_exposure_s += stall
         self._note_degraded(counts_np, [(r, h) for r, (i, h)
                                         in enumerate(group)])
+        if self.logits_hook is not None:
+            self.logits_hook("prefill", logits,
+                             [(r, h) for r, (i, h) in enumerate(group)])
         amax = np.asarray(jnp.argmax(logits, -1), np.int32)
         samp = self._gather_sampling_rows(
             logits, [r for r, (i, h) in enumerate(group)
@@ -1753,6 +1777,8 @@ class InferenceEngine:
                 jnp.asarray(row_valid))
         logits.block_until_ready()
         dt = time.perf_counter() - t0
+        if self.logits_hook is not None:
+            self.logits_hook("decode", logits, list(active))
         if ssm_old is not None:
             sub_new = {p: self.caches.blocks[p] for p in self._mamba_pos}
             merged = _merge_rows(sub_new, ssm_old, jnp.asarray(row_valid))

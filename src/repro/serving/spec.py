@@ -461,6 +461,8 @@ class SpecDecoder:
                 capacity_factor=cf, moe_dispatch=md, row_capacity=rc)
         logits_dev.block_until_ready()
         dt = time.perf_counter() - t0
+        if eng.logits_hook is not None:
+            eng.logits_hook("verify", logits_dev, list(active))
         # Greedy fast path: only the (W, B) device-side argmax crosses to
         # host; full (W, ·, V) f32 logits ship only for the rows that
         # genuinely sample (gathered on device first, so greedy neighbors

@@ -20,7 +20,7 @@ def test_quant_matmul_sweep(bits, m, k, n, dtype):
     x = jax.random.normal(key, (m, k), dtype)
     w = jax.random.normal(jax.random.PRNGKey(1), (k, n), jnp.float32)
     qt = quantize(w, bits=bits, group_size=64)
-    out = quant_matmul_op(x, qt, bm=128, bn=128, bk=256)
+    out = quant_matmul_op(x, qt, bm=128, bn=128)
     want = ref.quant_matmul_ref(x, qt.packed, qt.scales, bits, 64)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32),
@@ -34,7 +34,7 @@ def test_grouped_quant_matmul_sweep(bits, e, c, k, n):
     xg = jax.random.normal(key, (e, c, k), jnp.bfloat16)
     w = jax.random.normal(jax.random.PRNGKey(2), (e, k, n), jnp.float32)
     qt = quantize(w, bits=bits, group_size=64)
-    out = grouped_quant_matmul_op(xg, qt, bm=128, bn=128, bk=128)
+    out = grouped_quant_matmul_op(xg, qt, bm=128, bn=128)
     want = ref.grouped_quant_matmul_ref(xg, qt.packed, qt.scales, bits, 64)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32),

@@ -52,6 +52,39 @@ def test_stats_schema_uniform(serving_setup, engine_factory):
             assert st["promotions"] == 0 and st["demotions"] == 0
 
 
+def test_logits_hook_sees_every_emitting_forward(serving_setup,
+                                                 engine_factory):
+    """The logits hook is called once per forward with the rows it served:
+    under greedy sampling each request's j-th token is the argmax of the
+    row the hook saw when that request held j tokens."""
+    cfg, _ = serving_setup
+    eng = engine_factory("static", max_slots=2)
+    seen, kinds = {}, []
+
+    def hook(kind, logits, rows):
+        kinds.append(kind)
+        host = np.asarray(logits)
+        for r, h in rows:
+            seen[(h.id, len(h.tokens))] = int(host[r].argmax())
+
+    eng.logits_hook = hook
+    hs = [eng.submit(Request(tokens=p, max_new_tokens=4))
+          for p in make_prompts("text", cfg.vocab_size, 3, 12)]
+    eng.drain()
+    assert kinds.count("prefill") >= 2 and "decode" in kinds
+    for h in hs:
+        assert [seen[(h.id, j)] for j in range(4)] == list(h.tokens)
+
+
+def test_decode_program_text_holds_the_decode_step(engine_factory):
+    """The engine lowers its own decode program on request, without
+    serving a step."""
+    eng = engine_factory("static", max_slots=2)
+    text = eng.decode_program_text()
+    assert "func.func public @main" in text
+    assert eng.counters["steps"] == 0
+
+
 # ---------------------------------------------------------------------------
 # Continuous batching
 # ---------------------------------------------------------------------------
