@@ -5,10 +5,9 @@ jit path — everything it records is host-side Python on already-fetched
 counters).
 
 The obs-on run's trace is saved to ``experiments/obs.trace.json`` (Chrome
-trace-event JSON, viewable in Perfetto) and replayed through
-``repro.obs.costmodel`` so the artifact also carries the measured-vs-roofline
-bytes/token residuals and the promotion publish-latency percentiles — the
-validation half of the PR, regenerated on every benchmark run.
+trace-event JSON, viewable in Perfetto) and read back through
+``repro.obs.report`` so the artifact also carries the promotion
+publish-latency percentiles, regenerated on every benchmark run.
 
 ``BENCH_SMOKE=1`` shrinks reps/tokens for CI.
 """
@@ -56,7 +55,8 @@ def _serve_once(cfg, params, toks, obs):
 
 
 def run(report):
-    from repro.obs import Observability, ObsConfig, costmodel
+    from repro.obs import Observability, ObsConfig
+    from repro.obs import report as obs_report
     cfg, params, task = trained_model()
     toks = list(task.sample(BATCH, PROMPT, seed=3))
     _serve_once(cfg, params, toks, None)               # warm-up compile
@@ -71,21 +71,16 @@ def run(report):
     overhead = 1.0 - tps["on"] / tps["off"]
 
     last_obs.tracer.save(TRACE_OUT)
-    model = costmodel.report(last_obs.tracer)
-    roof, prom = model["roofline"], model["promotions"]
-    max_resid = max((abs(b["rel_residual"]) for b in roof["buckets"]),
-                    default=0.0)
+    prom = obs_report(last_obs.tracer)["promotions"]
 
     report("obs/tokens_per_s/off", 0.0, round(tps["off"], 2))
     report("obs/tokens_per_s/on", 0.0, round(tps["on"], 2))
     report("obs/overhead_frac", 0.0, round(overhead, 4))
-    report("obs/roofline_max_abs_residual", 0.0, round(max_resid, 4))
     report("obs/promotion_publish_p95_ms", 0.0,
            round(prom["publish_latency_p95_s"] * 1e3, 2))
     print(f"obs overhead: {overhead*100:+.1f}% "
           f"({tps['off']:.1f} -> {tps['on']:.1f} tok/s, best of {REPS}); "
-          f"roofline residual max {max_resid:.3f} over {roof['n_steps']} "
-          f"decode steps; {prom['n_published']} promotions published "
+          f"{prom['n_published']} promotions published "
           f"(p95 {prom['publish_latency_p95_s']*1e3:.1f} ms)")
 
     results = {"obs": {
@@ -93,7 +88,7 @@ def run(report):
         "overhead_frac": overhead, "max_overhead_frac": MAX_OVERHEAD,
         "reps": REPS, "smoke": BENCH_SMOKE,
         "trace_events": len(last_obs.tracer),
-        "roofline": roof, "promotions": prom,
+        "promotions": prom,
     }}
     os.makedirs(os.path.dirname(JSON_OUT), exist_ok=True)
     merged = {}

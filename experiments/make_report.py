@@ -77,15 +77,14 @@ def fmt_dryrun(recs, mesh):
 
 
 def fmt_obs(path="experiments/BENCH_obs.json"):
-    """§Observability tables from the obs_overhead benchmark artifact:
-    promotion publish-latency percentiles and the measured-vs-roofline
-    bytes/token residuals per (tokens, hi-mix) bucket."""
+    """§Observability table from the obs_overhead benchmark artifact:
+    the tax of the obs layer and promotion publish-latency percentiles."""
     try:
         with open(path) as f:
             obs = json.load(f)["obs"]
     except (FileNotFoundError, KeyError):
         return None
-    prom, roof = obs["promotions"], obs["roofline"]
+    prom = obs["promotions"]
     rows = [
         "### Observability tax + promotion latency",
         "",
@@ -100,19 +99,7 @@ def fmt_obs(path="experiments/BENCH_obs.json"):
         f"{prom['publish_latency_p50_s']*1e3:.1f} ms, p95 "
         f"{prom['publish_latency_p95_s']*1e3:.1f} ms, max "
         f"{prom['publish_latency_max_s']*1e3:.1f} ms.",
-        "",
-        "### Measured vs roofline MoE bytes/token "
-        f"({roof['n_steps']} decode steps)",
-        "",
-        "| tokens/step | published hi/layer | steps | measured B/tok | "
-        "predicted B/tok | residual |",
-        "|---|---|---|---|---|---|",
     ]
-    for b in roof["buckets"]:
-        rows.append(
-            f"| {b['tokens']:g} | {b['hi_per_layer']:g} | {b['n_steps']} | "
-            f"{b['measured_bpt']:,.0f} | {b['predicted_bpt']:,.0f} | "
-            f"{b['rel_residual']*100:+.2f}% |")
     return "\n".join(rows)
 
 

@@ -36,6 +36,7 @@ from repro.core.pools import ShardedSlotPool
 from repro.core.ver import ExpertBankQ, Residency, write_hi_slot
 from repro.fault.inject import TransferFault
 from repro.fault.retry import RetryExhausted, RetryPolicy, retry_call
+from repro.obs.trace import span
 
 
 @dataclasses.dataclass
@@ -229,9 +230,12 @@ class TransitionManager:
 
         seed = self.injector.seed if self.injector is not None else 0
         try:
-            new_hi, retries, _ = retry_call(
-                attempt, self.retry, seed=seed, key=(layer << 16) | expert,
-                site="promo_copy", tracer=self.tracer)
+            with span(self.tracer, "promote", "residency", layer=layer,
+                      expert=expert, bytes=self.hi_bytes):
+                new_hi, retries, _ = retry_call(
+                    attempt, self.retry, seed=seed,
+                    key=(layer << 16) | expert, site="promo_copy",
+                    tracer=self.tracer)
         except (RetryExhausted, TransferFault) as e:
             self._abort_issue(layer, expert, slot, e)
             return False

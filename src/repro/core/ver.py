@@ -190,10 +190,11 @@ def write_hi_slot(hi: Dict[str, jax.Array], layer: jax.Array,
     flight; it still runs asynchronously to the host, which dispatches the
     next step without waiting for it.
     """
-    return {n: jax.lax.dynamic_update_slice(
-                leaf, w[n].astype(leaf.dtype)[None, None],
-                (layer, slot) + (0,) * w[n].ndim)
-            for n, leaf in hi.items()}
+    with jax.named_scope("residency/promote"):
+        return {n: jax.lax.dynamic_update_slice(
+                    leaf, w[n].astype(leaf.dtype)[None, None],
+                    (layer, slot) + (0,) * w[n].ndim)
+                for n, leaf in hi.items()}
 
 
 @jax.jit

@@ -161,44 +161,6 @@ def model_flops(cfg, kind: str, tokens: int) -> float:
     return 2.0 * n_active * tokens
 
 
-def expected_active_experts(tokens: float, num_experts: int,
-                            top_k: int) -> float:
-    """Coupon-collector expectation: distinct experts activated by
-    ``tokens`` independent top-k draws under a uniform router —
-    ``E · (1 − (1 − k/E)^T)``. Real (skewed, temporally correlated) routing
-    activates fewer; the gap is exactly what the trace-driven cost model
-    (``repro.obs.costmodel``) measures as a residual."""
-    if tokens <= 0:
-        return 0.0
-    E = float(num_experts)
-    return E * (1.0 - (1.0 - top_k / E) ** tokens)
-
-
-def predict_moe_bytes_per_token(tokens: float, layers: int, num_experts: int,
-                                top_k: int, lo_bytes: int, hi_bytes: int,
-                                published_hi: int = 0,
-                                dispatch: str = "ragged") -> float:
-    """Analytic expert-weight HBM traffic of ONE MoE forward, per routed
-    token — the prediction the flight-recorder replay validates.
-
-    ``layers`` is the number of MoE layer-steps in the forward (all
-    positions × superblocks); ``published_hi`` the total published hi cells
-    across those layers. ``padded`` streams every layer's full lo tier plus
-    every published hi slot regardless of routing; ``ragged`` streams only
-    the expected active experts at their resident tier (hi cells assumed
-    uniformly spread, i.e. hit proportionally to their population)."""
-    if tokens <= 0 or layers <= 0:
-        return 0.0
-    if dispatch == "padded":
-        total = layers * num_experts * lo_bytes + published_hi * hi_bytes
-        return total / tokens
-    act = expected_active_experts(tokens, num_experts, top_k)
-    hi_frac = published_hi / float(layers * num_experts)
-    act_hi = act * hi_frac
-    act_lo = act - act_hi
-    return layers * (act_lo * lo_bytes + act_hi * hi_bytes) / tokens
-
-
 def analyze(compiled, hlo_text: str, cfg, kind: str, tokens: int,
             chips: int) -> Roofline:
     ca = compiled.cost_analysis()

@@ -275,17 +275,13 @@ def main():
               f"{ {k: round(float(v), 4) for k, v in st.items()} }")
     else:
         summ = obs.summary()
-        roof, prom = summ["roofline"], summ["promotions"]
-        resid = max((abs(b["rel_residual"]) for b in roof["buckets"]),
-                    default=0.0)
+        prom = summ["promotions"]
         stall = sum(h.stall_exposure_s for h in handles)
         print(f"[serve] obs: {summ['trace_events']} events "
               f"({summ['trace_dropped']} dropped)  "
               f"promotions {prom['n_published']} published / "
               f"{prom['n_cancelled']} cancelled "
               f"publish p95 {prom['publish_latency_p95_s']*1e3:.1f} ms  "
-              f"bytes/token residual max {resid:.3f} "
-              f"over {roof['n_steps']} decode steps  "
               f"stall exposure {stall*1e3:.1f} ms  "
               f"shed {st.get('shed_requests', 0.0):.0f}")
         if args.trace_out:

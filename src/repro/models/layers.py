@@ -285,30 +285,31 @@ def attention_prefill(p: Param, cfg: AttnConfig, x: jax.Array,
         mask = jnp.broadcast_to(mask, (B, S, S))
         out = _sdpa(q, k, v, mask, cfg.n_heads)
 
-    C = cache.capacity
-    kc = k.transpose(0, 2, 1, 3)       # (B, Hkv, S, hd) — cache layout
-    vc = v.transpose(0, 2, 1, 3)
-    if lengths is not None:
-        # Per-row masked write (full and ring caches alike): slot i takes
-        # the row's largest real position p < length with p % C == i; slots
-        # with no real owner keep their previous contents.
-        last = lengths[:, None] - 1 - \
-            jnp.mod(lengths[:, None] - 1 - jnp.arange(C)[None, :], C)  # (B,C)
-        has_owner = (last >= 0) & (lengths[:, None] > 0)
-        src = jnp.clip(last, 0, S - 1)[:, None, :, None]
-        gk = jnp.take_along_axis(kc, src, axis=2)        # (B, Hkv, C, hd)
-        gv = jnp.take_along_axis(vc, src, axis=2)
-        keep = has_owner[:, None, :, None]
-        new_k = jnp.where(keep, gk, cache.k)
-        new_v = jnp.where(keep, gv, cache.v)
-    elif C >= S:
-        new_k = jax.lax.dynamic_update_slice(cache.k, kc, (0, 0, 0, 0))
-        new_v = jax.lax.dynamic_update_slice(cache.v, vc, (0, 0, 0, 0))
-    else:  # ring buffer: keep last C positions, slot = pos % C
-        tail_k, tail_v = kc[:, :, S - C:], vc[:, :, S - C:]
-        slots = (jnp.arange(S - C, S)) % C
-        new_k = cache.k.at[:, :, slots].set(tail_k)
-        new_v = cache.v.at[:, :, slots].set(tail_v)
+    with jax.named_scope("kv_write"):
+        C = cache.capacity
+        kc = k.transpose(0, 2, 1, 3)       # (B, Hkv, S, hd) — cache layout
+        vc = v.transpose(0, 2, 1, 3)
+        if lengths is not None:
+            # Per-row masked write (full and ring caches alike): slot i takes
+            # the row's largest real position p < length with p % C == i; slots
+            # with no real owner keep their previous contents.
+            last = lengths[:, None] - 1 - jnp.mod(          # (B, C)
+                lengths[:, None] - 1 - jnp.arange(C)[None, :], C)
+            has_owner = (last >= 0) & (lengths[:, None] > 0)
+            src = jnp.clip(last, 0, S - 1)[:, None, :, None]
+            gk = jnp.take_along_axis(kc, src, axis=2)        # (B, Hkv, C, hd)
+            gv = jnp.take_along_axis(vc, src, axis=2)
+            keep = has_owner[:, None, :, None]
+            new_k = jnp.where(keep, gk, cache.k)
+            new_v = jnp.where(keep, gv, cache.v)
+        elif C >= S:
+            new_k = jax.lax.dynamic_update_slice(cache.k, kc, (0, 0, 0, 0))
+            new_v = jax.lax.dynamic_update_slice(cache.v, vc, (0, 0, 0, 0))
+        else:  # ring buffer: keep last C positions, slot = pos % C
+            tail_k, tail_v = kc[:, :, S - C:], vc[:, :, S - C:]
+            slots = (jnp.arange(S - C, S)) % C
+            new_k = cache.k.at[:, :, slots].set(tail_k)
+            new_v = cache.v.at[:, :, slots].set(tail_v)
     return out @ p["wo"], KVCache(new_k, new_v)
 
 
@@ -366,9 +367,11 @@ def attention_decode(p: Param, cfg: AttnConfig, x: jax.Array, pos: jax.Array,
     # dynamic-index DUS over the sharded seq axis through an f32 masked
     # fallback (measured ~4x the bytes); the explicit where-mask stays bf16
     # and costs exactly one cache read+write.
-    slot_mask = (jnp.arange(C)[None, :] == slot[:, None])[:, None, :, None]
-    new_k = jnp.where(slot_mask, k.transpose(0, 2, 1, 3), cache.k)
-    new_v = jnp.where(slot_mask, v.transpose(0, 2, 1, 3), cache.v)
+    with jax.named_scope("kv_write"):
+        slot_mask = (jnp.arange(C)[None, :] ==
+                     slot[:, None])[:, None, :, None]
+        new_k = jnp.where(slot_mask, k.transpose(0, 2, 1, 3), cache.k)
+        new_v = jnp.where(slot_mask, v.transpose(0, 2, 1, 3), cache.v)
     out = _attend_cache(q, new_k, new_v, pos_b, cfg)
     return out @ p["wo"], KVCache(new_k, new_v)
 
@@ -388,9 +391,9 @@ def attention_decode_paged(p: Param, cfg: AttnConfig, x: jax.Array,
     pos = jnp.asarray(pos, jnp.int32)
     pos_b = jnp.broadcast_to(pos, (B,)) if pos.ndim == 0 else pos
     q, k, v = _project_qkv(p, cfg, x, pos_b[:, None])
-    new_k = cache.k.at[write_blk, :, write_off].set(k[:, 0])
-    new_v = cache.v.at[write_blk, :, write_off].set(v[:, 0])
-    cache = PagedKVCache(new_k, new_v)
+    with jax.named_scope("kv_write"):
+        cache = PagedKVCache(cache.k.at[write_blk, :, write_off].set(k[:, 0]),
+                             cache.v.at[write_blk, :, write_off].set(v[:, 0]))
     k_all, v_all = paged_view(cache, table)
     out = _attend_cache(q, k_all, v_all, pos_b, cfg)
     return out @ p["wo"], cache
@@ -430,23 +433,24 @@ def attention_prefill_paged(p: Param, cfg: AttnConfig, x: jax.Array,
         # the gather ordered ahead of the scatter inside one jit).
         pk, pv = paged_view(cache, table)                # (B, Hkv, C, hd)
 
-    # ---- scatter suffix K/V: slot s takes the row's largest real position
-    # p in [start, length) with p % C == s; all other lanes hit the trash
-    # block (never a live one).
-    idx = jnp.arange(C)[None, :]
-    last = lengths[:, None] - 1 - jnp.mod(lengths[:, None] - 1 - idx, C)
-    own = (last >= start[:, None]) & (lengths[:, None] > start[:, None])
-    src = jnp.clip(last - start[:, None], 0, S - 1)
-    kc = k.transpose(0, 2, 1, 3)                          # (B, Hkv, S, hd)
-    vc = v.transpose(0, 2, 1, 3)
-    gk = jnp.take_along_axis(kc, src[:, None, :, None], axis=2)
-    gv = jnp.take_along_axis(vc, src[:, None, :, None], axis=2)
-    blk = jnp.take_along_axis(table, jnp.broadcast_to(idx // bt, (B, C)),
-                              axis=1)
-    phys = jnp.where(own, jnp.clip(blk, 0), 0)
-    offs = jnp.broadcast_to(idx % bt, (B, C))
-    new_k = cache.k.at[phys, :, offs].set(gk.transpose(0, 2, 1, 3))
-    new_v = cache.v.at[phys, :, offs].set(gv.transpose(0, 2, 1, 3))
+    with jax.named_scope("kv_write"):
+        # ---- scatter suffix K/V: slot s takes the row's largest real position
+        # p in [start, length) with p % C == s; all other lanes hit the trash
+        # block (never a live one).
+        idx = jnp.arange(C)[None, :]
+        last = lengths[:, None] - 1 - jnp.mod(lengths[:, None] - 1 - idx, C)
+        own = (last >= start[:, None]) & (lengths[:, None] > start[:, None])
+        src = jnp.clip(last - start[:, None], 0, S - 1)
+        kc = k.transpose(0, 2, 1, 3)                          # (B, Hkv, S, hd)
+        vc = v.transpose(0, 2, 1, 3)
+        gk = jnp.take_along_axis(kc, src[:, None, :, None], axis=2)
+        gv = jnp.take_along_axis(vc, src[:, None, :, None], axis=2)
+        blk = jnp.take_along_axis(table, jnp.broadcast_to(idx // bt, (B, C)),
+                                  axis=1)
+        phys = jnp.where(own, jnp.clip(blk, 0), 0)
+        offs = jnp.broadcast_to(idx % bt, (B, C))
+        new_k = cache.k.at[phys, :, offs].set(gk.transpose(0, 2, 1, 3))
+        new_v = cache.v.at[phys, :, offs].set(gv.transpose(0, 2, 1, 3))
 
     # ---- suffix queries over [cached prefix ⊕ suffix] ------------------
     qpos = positions[:, :, None]                          # (B, S, 1)

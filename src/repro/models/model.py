@@ -210,11 +210,11 @@ def _apply_ffn(bp: Dict, cfg: ArchConfig, pos: int, x2d: jax.Array,
     ffn = cfg.ffn_kind(pos)
     if ffn == "moe":
         b = bank[str(pos)] if bank is not None else bp["moe"]["experts"]
-        y, aux = X.moe_apply(bp["moe"], b, x2d, cfg.moe, capacity,
-                             token_valid=token_valid, n_rows=n_rows,
-                             row_capacity=row_capacity,
-                             dispatch=moe_dispatch)
-        return y, aux
+        with jax.named_scope("moe"):
+            return X.moe_apply(bp["moe"], b, x2d, cfg.moe, capacity,
+                               token_valid=token_valid, n_rows=n_rows,
+                               row_capacity=row_capacity,
+                               dispatch=moe_dispatch)
     if "mlp" in bp:
         return M.swiglu(bp["mlp"], x2d), None
     return jnp.zeros_like(x2d), None
@@ -261,30 +261,33 @@ def _block_step(bp: Dict, cfg: ArchConfig, pos: int, kind: str, x: jax.Array,
     B, Sq, d = x.shape
     h = L.rmsnorm(bp["norm1"], x, cfg.norm_eps)
     if kind == "attn":
-        if paged is not None:
-            if prefill:
-                # paged["lengths"] are TOTAL prompt lengths (prefix + the
-                # suffix being computed); the ``lengths`` param carries the
-                # suffix lengths for the non-paged (mamba) positions.
-                attn_out, cache = L.attention_prefill_paged(
-                    bp["attn"], cfg.attn, h, cache, paged["table"],
-                    paged["start"], paged["lengths"],
-                    has_prefix=paged["has_prefix"])
+        with jax.named_scope("attn"):
+            if paged is not None:
+                if prefill:
+                    # paged["lengths"] are TOTAL prompt lengths (prefix +
+                    # the suffix being computed); the ``lengths`` param
+                    # carries the suffix lengths for the non-paged (mamba)
+                    # positions.
+                    attn_out, cache = L.attention_prefill_paged(
+                        bp["attn"], cfg.attn, h, cache, paged["table"],
+                        paged["start"], paged["lengths"],
+                        has_prefix=paged["has_prefix"])
+                else:
+                    attn_out, cache = L.attention_decode_paged(
+                        bp["attn"], cfg.attn, h, pos_idx, cache,
+                        paged["table"], paged["write_blk"],
+                        paged["write_off"])
+            elif prefill:
+                attn_out, cache = L.attention_prefill(
+                    bp["attn"], cfg.attn, h, cache, lengths=lengths)
             else:
-                attn_out, cache = L.attention_decode_paged(
-                    bp["attn"], cfg.attn, h, pos_idx, cache,
-                    paged["table"], paged["write_blk"], paged["write_off"])
-        elif prefill:
-            attn_out, cache = L.attention_prefill(bp["attn"], cfg.attn, h,
-                                                  cache, lengths=lengths)
-        else:
-            attn_out, cache = L.attention_decode(bp["attn"], cfg.attn, h,
-                                                 pos_idx, cache)
-        if cfg.is_encoder_decoder:
-            x = x + attn_out
-            hc = L.rmsnorm(bp["norm_cross"], x, cfg.norm_eps)
-            attn_out = L.cross_attention(bp["cross"], cfg.attn, hc,
-                                         cross_kv["k"], cross_kv["v"])
+                attn_out, cache = L.attention_decode(
+                    bp["attn"], cfg.attn, h, pos_idx, cache)
+            if cfg.is_encoder_decoder:
+                x = x + attn_out
+                hc = L.rmsnorm(bp["norm_cross"], x, cfg.norm_eps)
+                attn_out = L.cross_attention(bp["cross"], cfg.attn, hc,
+                                             cross_kv["k"], cross_kv["v"])
     else:
         if prefill:
             attn_out, cache = S.ssd_forward(bp["mamba"], cfg.ssm,
@@ -342,9 +345,10 @@ def _embed_inputs(params: Dict, cfg: ArchConfig, batch: Dict) -> jax.Array:
 
 
 def _lm_logits(params: Dict, cfg: ArchConfig, x: jax.Array) -> jax.Array:
-    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    return (x @ head).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        return (x @ head).astype(jnp.float32)
 
 
 # --------------------------------------------------------------------------

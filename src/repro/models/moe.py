@@ -209,46 +209,50 @@ def dispatch_compute(bank, x: jax.Array, idx: jax.Array, gates: jax.Array,
     impossible). Returns (y (T, d), counts (e_local,), dropped scalar).
     """
     T, d = x.shape
-    order, sorted_eid, counts, pos_in_e, tok = _sort_routing(idx, e_local)
-    valid = _keep_mask(sorted_eid, pos_in_e, tok, e_local, capacity,
-                       row_capacity, n_rows, T)
-    if row_capacity is None:
-        scat_pos = pos_in_e
-    else:
-        # Scatter by rank among KEPT assignments of the expert so the
-        # physical buffer only ever holds survivors.
-        kept_i = valid.astype(jnp.int32)
-        inc = jnp.cumsum(kept_i)
-        kept_e = jnp.zeros((e_local + 1,), jnp.int32) \
-            .at[sorted_eid].add(kept_i)
-        kstart = jnp.cumsum(kept_e) - kept_e
-        scat_pos = jnp.where(valid, inc - 1 - kstart[sorted_eid], capacity)
+    with jax.named_scope("dispatch"):
+        order, sorted_eid, counts, pos_in_e, tok = _sort_routing(idx, e_local)
+        valid = _keep_mask(sorted_eid, pos_in_e, tok, e_local, capacity,
+                           row_capacity, n_rows, T)
+        if row_capacity is None:
+            scat_pos = pos_in_e
+        else:
+            # Scatter by rank among KEPT assignments of the expert so the
+            # physical buffer only ever holds survivors.
+            kept_i = valid.astype(jnp.int32)
+            inc = jnp.cumsum(kept_i)
+            kept_e = jnp.zeros((e_local + 1,), jnp.int32) \
+                .at[sorted_eid].add(kept_i)
+            kstart = jnp.cumsum(kept_e) - kept_e
+            scat_pos = jnp.where(valid, inc - 1 - kstart[sorted_eid], capacity)
 
-    xg = jnp.zeros((e_local, capacity, d), x.dtype)
-    xg = xg.at[sorted_eid, scat_pos].set(x[tok], mode="drop")
+        xg = jnp.zeros((e_local, capacity, d), x.dtype)
+        xg = xg.at[sorted_eid, scat_pos].set(x[tok], mode="drop")
 
-    if isinstance(bank, ExpertBankQ):
-        yg = _quant_expert_ffn(bank, xg, e_offset=e_offset, e_local=e_local,
-                               slot_lo=slot_lo, n_slot_local=n_slot_local,
-                               ff_axis=ff_axis, gemm=gemm)
-    else:
-        w = bank
-        h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xg, w["w_gate"])
-                        .astype(jnp.float32)).astype(x.dtype)
-        h = h * jnp.einsum("ecd,edf->ecf", xg, w["w_up"])
-        yg = jnp.einsum("ecf,efd->ecd", h, w["w_down"])
+    with jax.named_scope("ffn"):
+        if isinstance(bank, ExpertBankQ):
+            yg = _quant_expert_ffn(bank, xg, e_offset=e_offset,
+                                   e_local=e_local, slot_lo=slot_lo,
+                                   n_slot_local=n_slot_local,
+                                   ff_axis=ff_axis, gemm=gemm)
+        else:
+            w = bank
+            h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xg, w["w_gate"])
+                            .astype(jnp.float32)).astype(x.dtype)
+            h = h * jnp.einsum("ecd,edf->ecf", xg, w["w_up"])
+            yg = jnp.einsum("ecf,efd->ecd", h, w["w_down"])
 
-    pos_safe = jnp.minimum(scat_pos, capacity - 1)
-    eid_safe = jnp.minimum(sorted_eid, e_local - 1)
-    y_sorted = yg[eid_safe, pos_safe]
-    gate_sorted = gates.reshape(-1)[order].astype(x.dtype)
-    contrib = jnp.where(valid[:, None], y_sorted * gate_sorted[:, None], 0)
-    # yg's output-feature dim may be data-sliced under 2-D expert sharding.
-    y = jnp.zeros((T, yg.shape[-1]), x.dtype).at[tok].add(contrib)
+    with jax.named_scope("combine"):
+        pos_safe = jnp.minimum(scat_pos, capacity - 1)
+        eid_safe = jnp.minimum(sorted_eid, e_local - 1)
+        y_sorted = yg[eid_safe, pos_safe]
+        gate_sorted = gates.reshape(-1)[order].astype(x.dtype)
+        contrib = jnp.where(valid[:, None], y_sorted * gate_sorted[:, None], 0)
+        # yg's output-feature dim may be data-sliced under 2-D expert sharding.
+        y = jnp.zeros((T, yg.shape[-1]), x.dtype).at[tok].add(contrib)
 
-    routed = jnp.sum(jnp.where(sorted_eid < e_local, 1.0, 0.0))
-    kept = jnp.sum(jnp.where(valid, 1.0, 0.0))
-    dropped = 1.0 - kept / jnp.maximum(routed, 1.0)
+        routed = jnp.sum(jnp.where(sorted_eid < e_local, 1.0, 0.0))
+        kept = jnp.sum(jnp.where(valid, 1.0, 0.0))
+        dropped = 1.0 - kept / jnp.maximum(routed, 1.0)
     return y, counts.astype(jnp.int32), dropped
 
 
@@ -355,49 +359,54 @@ def _dispatch_ragged(bank: Union[Dict, ExpertBankQ], x: jax.Array,
 
     Returns (y (T, D), counts (E,), dropped, pad_ratio)."""
     T, d = x.shape
-    Tk = T * idx.shape[1]
-    bm = RAGGED_BM
-    order, sorted_eid, counts, pos_in_e, tok = _sort_routing(idx, e_local)
-    kept = _keep_mask(sorted_eid, pos_in_e, tok, e_local, capacity,
-                      row_capacity, n_rows, T)
-    astart, tile_eid, n_tiles = ragged_tile_map(counts, bm, Tk)
-    R = tile_eid.shape[0] * bm
-    safe_e = jnp.minimum(sorted_eid, e_local - 1)
-    rowpos = jnp.where(sorted_eid < e_local,
-                       astart[safe_e] + pos_in_e, R)        # sentinel → drop
-    xs = jnp.zeros((R, d), x.dtype).at[rowpos].set(x[tok], mode="drop")
+    with jax.named_scope("dispatch"):
+        Tk = T * idx.shape[1]
+        bm = RAGGED_BM
+        order, sorted_eid, counts, pos_in_e, tok = _sort_routing(idx, e_local)
+        kept = _keep_mask(sorted_eid, pos_in_e, tok, e_local, capacity,
+                          row_capacity, n_rows, T)
+        astart, tile_eid, n_tiles = ragged_tile_map(counts, bm, Tk)
+        R = tile_eid.shape[0] * bm
+        safe_e = jnp.minimum(sorted_eid, e_local - 1)
+        rowpos = jnp.where(sorted_eid < e_local,              # sentinel
+                           astart[safe_e] + pos_in_e, R)    # → drop
+        xs = jnp.zeros((R, d), x.dtype).at[rowpos].set(x[tok], mode="drop")
 
-    if isinstance(bank, ExpertBankQ):
-        # Stable handles: expert → hi slot derived from slot_owner (NOT
-        # slot_map), matching the padded overlay's semantics — a draft bank
-        # that disowns every slot is all-lo under both layouts.
-        owner = bank.slot_owner                              # (n_hi,)
-        n_hi = owner.shape[0]
-        if n_hi > 0:
-            eff_map = jnp.full((e_local + 1,), -1, jnp.int32).at[
-                jnp.where(owner >= 0, owner, e_local)].set(
-                jnp.arange(n_hi, dtype=jnp.int32), mode="drop")[:e_local]
-            tile_slot = eff_map[tile_eid]
+    with jax.named_scope("ffn"):
+        if isinstance(bank, ExpertBankQ):
+            # Stable handles: expert → hi slot derived from slot_owner
+            # (NOT slot_map), matching the padded overlay's semantics — a
+            # draft bank that disowns every slot is all-lo under both
+            # layouts.
+            owner = bank.slot_owner                              # (n_hi,)
+            n_hi = owner.shape[0]
+            if n_hi > 0:
+                eff_map = jnp.full((e_local + 1,), -1, jnp.int32).at[
+                    jnp.where(owner >= 0, owner, e_local)].set(
+                    jnp.arange(n_hi, dtype=jnp.int32), mode="drop")[:e_local]
+                tile_slot = eff_map[tile_eid]
+            else:
+                tile_slot = jnp.full_like(tile_eid, -1)
+
+            y_rows = kops.ragged_quant_ffn_op(
+                xs, tile_eid, tile_slot, bank.lo, bank.hi if n_hi else None,
+                bits=bank.lo["w_gate"].bits,
+                group=bank.lo["w_gate"].group_size, bm=bm, backend=gemm)
         else:
-            tile_slot = jnp.full_like(tile_eid, -1)
+            y_rows = kops.ragged_dense_ffn_op(xs, tile_eid, bank, bm=bm,
+                                              backend=gemm)
 
-        y_rows = kops.ragged_quant_ffn_op(
-            xs, tile_eid, tile_slot, bank.lo, bank.hi if n_hi else None,
-            bits=bank.lo["w_gate"].bits, group=bank.lo["w_gate"].group_size,
-            bm=bm, backend=gemm)
-    else:
-        y_rows = kops.ragged_dense_ffn_op(xs, tile_eid, bank, bm=bm,
-                                          backend=gemm)
+    with jax.named_scope("combine"):
+        y_asn = y_rows[jnp.minimum(rowpos, R - 1)]
+        gate_sorted = gates.reshape(-1)[order].astype(x.dtype)
+        contrib = jnp.where(kept[:, None], y_asn * gate_sorted[:, None], 0)
+        y = jnp.zeros((T, y_rows.shape[-1]), x.dtype).at[tok].add(contrib)
 
-    y_asn = y_rows[jnp.minimum(rowpos, R - 1)]
-    gate_sorted = gates.reshape(-1)[order].astype(x.dtype)
-    contrib = jnp.where(kept[:, None], y_asn * gate_sorted[:, None], 0)
-    y = jnp.zeros((T, y_rows.shape[-1]), x.dtype).at[tok].add(contrib)
-
-    routed = jnp.sum(jnp.where(sorted_eid < e_local, 1.0, 0.0))
-    kept_f = jnp.sum(jnp.where(kept, 1.0, 0.0))
-    dropped = 1.0 - kept_f / jnp.maximum(routed, 1.0)
-    pad_ratio = 1.0 - routed / jnp.maximum(n_tiles * bm, 1).astype(jnp.float32)
+        routed = jnp.sum(jnp.where(sorted_eid < e_local, 1.0, 0.0))
+        kept_f = jnp.sum(jnp.where(kept, 1.0, 0.0))
+        dropped = 1.0 - kept_f / jnp.maximum(routed, 1.0)
+        pad_ratio = 1.0 - routed / jnp.maximum(n_tiles * bm,
+                                               1).astype(jnp.float32)
     return y, counts.astype(jnp.int32), dropped, pad_ratio
 
 
@@ -421,7 +430,8 @@ def _moe_local(params: Dict, bank, x: jax.Array, cfg: MoEConfig,
     """
     E, k = cfg.num_experts, cfg.top_k
     T = x.shape[0]
-    gates, idx, probs = route(params["router"], x, cfg)
+    with jax.named_scope("router"):
+        gates, idx, probs = route(params["router"], x, cfg)
     sel = (idx >= e_offset) & (idx < e_offset + e_local)
     if token_valid is not None:
         sel = sel & token_valid[:, None]

@@ -1,4 +1,4 @@
-"""Observability layer: flight-recorder tracing, metrics, trace cost model.
+"""Observability layer: flight-recorder tracing and metrics.
 
 ``Observability`` bundles the two live components the serving engine
 threads through the stack:
@@ -11,10 +11,12 @@ threads through the stack:
 
 Both are optional and independently disableable; a ``None`` observability
 object (the default everywhere) keeps every instrumentation site a pointer
-check — the decode hot path is untouched.
+check — the decode hot path is untouched. The recorder's duration spans
+also land in the JAX profiler's trace (``FlightRecorder.span``), so a
+profile of a traced run names what the host did between device ops.
 
-``repro.obs.costmodel`` replays a recorded trace offline into measured
-bytes/token and validates ``launch/roofline.py``'s analytic model.
+``report`` reads promotion publish latency back from a live recorder or a
+saved trace.
 """
 from __future__ import annotations
 
@@ -24,7 +26,7 @@ from typing import Dict, Optional
 from repro.obs.metrics import (Counter, Gauge, Histogram,  # noqa: F401
                                MetricsRegistry)
 from repro.obs.trace import (FlightRecorder, TraceEvent,   # noqa: F401
-                             load_chrome_trace)
+                             load_chrome_trace, promotion_report, span)
 
 
 @dataclasses.dataclass
@@ -55,13 +57,11 @@ class Observability:
         self.tracer.save(path)
 
     def summary(self) -> Dict:
-        """Shutdown one-liner material: promotion publish percentiles and
-        the roofline residual (from the live recorder) plus the metrics
-        snapshot."""
+        """Shutdown one-liner material: promotion publish percentiles (from
+        the live recorder) plus the metrics snapshot."""
         out: Dict = {}
         if self.tracer is not None:
-            from repro.obs import costmodel
-            out.update(costmodel.report(self.tracer))
+            out.update(report(self.tracer))
             out["trace_events"] = len(self.tracer)
             out["trace_dropped"] = self.tracer.dropped
         if self.metrics is not None:
@@ -73,7 +73,15 @@ class Observability:
             self.metrics.close()
 
 
+def report(trace) -> Dict:
+    """What the shutdown summary and the benchmarks read from a trace (a
+    live recorder, a Chrome trace object or its path): promotion publish
+    latency and the half-materialization audit."""
+    return {"promotions": promotion_report(trace)}
+
+
 __all__ = [
     "Counter", "FlightRecorder", "Gauge", "Histogram", "MetricsRegistry",
     "ObsConfig", "Observability", "TraceEvent", "load_chrome_trace",
+    "promotion_report", "report", "span",
 ]

@@ -197,12 +197,6 @@ class _BackendBase:
         self.tracer = tracer
         self.metrics = metrics
 
-    def obs_meta(self) -> Dict[str, int]:
-        """Byte prices the trace cost model replays against:
-        ``{"lo_bytes", "hi_bytes"}`` per expert-layer cell (zeros where a
-        tier doesn't exist under this strategy)."""
-        return {}
-
     # -- lifecycle -------------------------------------------------------
     def materialize_banks(self, cfg: ArchConfig, params: Dict,
                           kv_bytes: int, budget=None) -> Optional[Dict]:
@@ -235,10 +229,10 @@ class _BackendBase:
             self._counts_sum[k] = c.copy() if acc is None else acc + c
         stall = self._observe_residency(cleaned, compute_s)
         if self.tracer is not None:
-            # The per-forward traffic record the cost model replays: routed
-            # assignments plus the active-cell tier split at THIS forward's
-            # residency. Args are counts only (no wall-clock durations), so
-            # virtual-clock replays trace byte-identically.
+            # The per-forward traffic record: routed assignments plus the
+            # active-cell tier split at THIS forward's residency. Args are
+            # counts only (no wall-clock durations), so virtual-clock
+            # replays trace byte-identically.
             hi, lo, host, pub = self._tier_counts(cleaned)
             self.tracer.instant(
                 "moe_forward", cat="engine",
@@ -313,7 +307,6 @@ class Fp16Backend(_BackendBase):
         super().__init__()
         self._dense_bytes = 0
         self._cells = 0
-        self._cell_bytes = 0
 
     def _materialize(self, cfg, params, kv_bytes):
         self._dense_bytes = sum(
@@ -323,7 +316,6 @@ class Fp16Backend(_BackendBase):
             int(np.prod(params["blocks"][str(p)]["moe"]["experts"]
                         ["w_gate"].shape[:2]))
             for p in self.moe_positions)
-        self._cell_bytes = self._dense_bytes // max(1, self._cells)
         return None        # forward uses the dense experts in params
 
     def _tier_counts(self, cleaned):
@@ -334,9 +326,6 @@ class Fp16Backend(_BackendBase):
 
     def residency_mix(self) -> Dict[str, int]:
         return {"hi": self._cells, "lo": 0, "host": 0}
-
-    def obs_meta(self) -> Dict[str, int]:
-        return {"lo_bytes": 0, "hi_bytes": self._cell_bytes}
 
     def device_bytes(self) -> int:
         return self._dense_bytes
@@ -355,16 +344,14 @@ class StaticPTQBackend(_BackendBase):
         self.banks: Dict = {}
         self._lo_bytes = 0
         self._cells = 0
-        self._lo_per = 0
 
     def _materialize(self, cfg, params, kv_bytes):
         for pos in self.moe_positions:
             experts = params["blocks"][str(pos)]["moe"]["experts"]
             shapes = {k: tuple(v.shape) for k, v in experts.items()}
             L, E = experts["w_gate"].shape[:2]
-            self._lo_per = expert_lo_nbytes(
-                shapes, self.lo_bits, self.group_size)
-            self._lo_bytes += self._lo_per * L * E
+            self._lo_bytes += expert_lo_nbytes(
+                shapes, self.lo_bits, self.group_size) * L * E
             self._cells += L * E
             self.banks[str(pos)] = build_bank(
                 experts, n_hi=0, lo_bits=self.lo_bits,
@@ -375,9 +362,6 @@ class StaticPTQBackend(_BackendBase):
 
     def residency_mix(self) -> Dict[str, int]:
         return {"hi": 0, "lo": self._cells, "host": 0}
-
-    def obs_meta(self) -> Dict[str, int]:
-        return {"lo_bytes": self._lo_per, "hi_bytes": 0}
 
     def device_bytes(self) -> int:
         return self._lo_bytes
@@ -491,7 +475,6 @@ class DynaExqBackend(_BackendBase):
         self._row_offsets: Dict[str, int] = {}
         self._sens: Dict[str, np.ndarray] = {}
         self._lo_b: Dict[str, int] = {}
-        self._hi_b: Dict[str, int] = {}
         self._pump_queue: deque = deque()
         self._lo_quota_left = lo_resident_total or 0
         self._serving_ready = True
@@ -576,7 +559,6 @@ class DynaExqBackend(_BackendBase):
         self._build_global_structures(metas, sens)
         for pos, experts, shapes, L, E, hi_b, lo_b, n_hi in metas:
             self._lo_b[pos] = lo_b
-            self._hi_b[pos] = hi_b
             slots = n_hi
             if self.global_alloc and n_hi > 0:
                 # Physical per-layer pool ceiling: headroom over the
@@ -725,12 +707,6 @@ class DynaExqBackend(_BackendBase):
             # (the engine rebinds the recorder's clock to its own).
             for ctl in self.controllers.values():
                 ctl.tm.clock = self.tracer.clock
-
-    def obs_meta(self) -> Dict[str, int]:
-        if not self._lo_b:
-            return {}
-        return {"lo_bytes": int(next(iter(self._lo_b.values()))),
-                "hi_bytes": int(next(iter(self._hi_b.values())))}
 
     def _tier_counts(self, cleaned):
         hi = lo = host = pub = 0
@@ -1245,9 +1221,6 @@ class OffloadBackend(_BackendBase):
             self.cfg.moe is not None else 0
         total = self.n_moe_layers * E
         return {"hi": hi, "lo": 0, "host": max(0, total - hi)}
-
-    def obs_meta(self) -> Dict[str, int]:
-        return {"lo_bytes": 0, "hi_bytes": self.expert_bytes}
 
     def device_bytes(self) -> int:
         """Device-resident cache footprint under the offload budget."""

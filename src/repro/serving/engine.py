@@ -85,6 +85,7 @@ from repro.models import (attn_logical_capacity, decode_step,
 from repro.models.config import ArchConfig
 from repro.models.moe import RAGGED_BM, moe_capacity
 from repro.models.model import DecodeCaches
+from repro.obs.trace import span
 from repro.serving.backends import ResidencyBackend
 from repro.serving.kvpool import KVBlockPool, KVLease
 from repro.serving.prefix import PrefixTrie
@@ -492,16 +493,13 @@ class InferenceEngine:
             raise ValueError(f"moe_dispatch={self.moe_dispatch!r}; "
                              f"one of padded|ragged")
         if self.tracer is not None:
-            # Trace metadata the offline cost model (repro.obs.costmodel)
-            # replays against: dispatch mode, router shape, byte prices.
+            # Run metadata exported with the trace: backend, dispatch mode,
+            # router shape.
             self.tracer.meta.update(
                 moe_dispatch=self.moe_dispatch,
                 num_experts=cfg.moe.num_experts if cfg.is_moe else 0,
                 top_k=cfg.moe.top_k if cfg.is_moe else 1,
-                lo_bytes=0, hi_bytes=0, backend=backend.name)
-            meta_fn = getattr(backend, "obs_meta", None)
-            if meta_fn is not None:
-                self.tracer.meta.update(meta_fn())
+                backend=backend.name)
         norm = self.ecfg.row_capacity_norm and cfg.is_moe
         self._row_cap_decode = moe_capacity(
             1, cfg.moe, self.ecfg.capacity_factor) if norm else None
@@ -644,10 +642,10 @@ class InferenceEngine:
                 return fn(*a, **kw)
         return wrapped
 
-    def decode_program_text(self) -> str:
-        """StableHLO text of the single-token decode program this engine
-        runs, lowered for its current state with every row vacant — where
-        a check looks for the kernels the step holds."""
+    def decode_program(self):
+        """The single-token decode program this engine runs, lowered
+        (``jax.stages.Lowered``) for its current state with every row
+        vacant: ``.compile().as_text()`` is its optimized HLO."""
         n = self.ecfg.max_slots
         args = [self.params, jnp.asarray(self.tokens), jnp.asarray(self.pos),
                 self.caches, self.banks, jnp.zeros(n, bool)]
@@ -657,7 +655,13 @@ class InferenceEngine:
             args += [jnp.asarray(self._block_tables()), zeros, zeros]
             fn = _decode_paged_jit
         with dist_ctx(self.dist):
-            return fn.lower(*args, **self._decode_kw).as_text()
+            return fn.lower(*args, **self._decode_kw)
+
+    def decode_program_text(self) -> str:
+        """StableHLO text of ``decode_program`` — where a check looks for
+        the kernels the step holds. Locations are kept, so the text names
+        each op's ``jax.named_scope`` (``attn``, ``moe/ffn``, …)."""
+        return self.decode_program().as_text(debug_info=True)
 
     # ------------------------------------------------------------------
     def _row_cap_prefill(self, bucket: int) -> Optional[int]:
@@ -806,7 +810,20 @@ class InferenceEngine:
                 self.tracer.instant("downgrade", cat="sched", rid=handle.id,
                                     qos=qos)
         self.queue.append(handle)
+        self._queued(handle)
         return handle
+
+    def _queued(self, handle: RequestHandle) -> None:
+        """Open the request's ``sched.queue`` span (submit or requeue)."""
+        if self.tracer is not None:
+            self.tracer.async_begin("queue", handle.id, cat="sched",
+                                    qos=handle.exec_qos)
+
+    def _dequeued(self, handle: RequestHandle, how: str) -> None:
+        """Close the request's ``sched.queue`` span: it left the queue for
+        a prefill group, a chunked admission or a resume (or was shed)."""
+        if self.tracer is not None:
+            self.tracer.async_end("queue", handle.id, cat="sched", how=how)
 
     def _bucket_len(self, plen: int) -> int:
         """Smallest ladder bucket that fits ``plen`` tokens."""
@@ -904,6 +921,7 @@ class InferenceEngine:
                     (now - h.submit_s) * 1e3 > d)
 
         for h in self.queue.prune(expired):
+            self._dequeued(h, "shed")
             h.state = RequestState.SHED
             self.counters["shed_requests"] += 1
             if self.tracer is not None:
@@ -927,10 +945,11 @@ class InferenceEngine:
         requests free the headroom that admits it. (Stacks without
         attention positions have no KV to page and always take the dense
         path.)"""
-        if self.pool is not None:
-            self._admit_paged(finished)
-        else:
-            self._admit_dense(finished)
+        with span(self.tracer, "admit", "sched"):
+            if self.pool is not None:
+                self._admit_paged(finished)
+            else:
+                self._admit_dense(finished)
 
     def _admit_dense(self, finished: List[RequestHandle]) -> None:
         while self.queue:
@@ -942,6 +961,7 @@ class InferenceEngine:
                 # Preempted request at the queue head: resume is a direct
                 # cache-row upload, not a prefill.
                 self.queue.popleft()
+                self._dequeued(head_peek, "resume")
                 self._resume_dense(head_peek, free[0])
                 continue
             R = self._prefill_rows
@@ -958,6 +978,8 @@ class InferenceEngine:
                 else:
                     skipped.append(h)
             self.queue.extendleft(reversed(skipped))
+            for h in group:
+                self._dequeued(h, "prefill")
 
             G = len(group)
             lengths = np.zeros(R, np.int32)
@@ -968,13 +990,15 @@ class InferenceEngine:
                 lengths[r] = p.shape[0]
                 batch_toks[r, :p.shape[0]] = p
             row_caches = init_caches(self.cfg, R, self.ecfg.max_len)
-            t0 = time.perf_counter()
-            logits, row_caches, counts = self._jit_prefill(
-                self.params, {"tokens": jnp.asarray(batch_toks)},
-                row_caches, self.banks, jnp.asarray(lengths),
-                row_capacity=self._row_cap_prefill(bucket))
-            logits.block_until_ready()
-            dt = time.perf_counter() - t0
+            with span(self.tracer, "prefill", "engine", rows=G,
+                      bucket=bucket):
+                t0 = time.perf_counter()
+                logits, row_caches, counts = self._jit_prefill(
+                    self.params, {"tokens": jnp.asarray(batch_toks)},
+                    row_caches, self.banks, jnp.asarray(lengths),
+                    row_capacity=self._row_cap_prefill(bucket))
+                logits.block_until_ready()
+                dt = time.perf_counter() - t0
             self.prefill_shapes.add((R, bucket))
             slots_arr = np.asarray(free[:G], np.int32)
             # Scatter the prefilled rows into their slots' batch rows.
@@ -1014,12 +1038,14 @@ class InferenceEngine:
                     # finishing request or expert demotion unblocks it.
                     self.queue.appendleft(head_peek)
                     return
+                self._dequeued(head_peek, "resume")
                 continue
             if head_peek is not None and self._chunk_eligible(head_peek):
                 self.queue.popleft()
                 if not self._begin_chunked(head_peek, free[0]):
                     self.queue.appendleft(head_peek)
                     return
+                self._dequeued(head_peek, "chunk")
                 continue
             R = self._prefill_rows
             limit = min(len(free), R)
@@ -1084,6 +1110,7 @@ class InferenceEngine:
                     lease.adopt_prefix(hits, retained=True)
                     h.prefix_hit_tokens = start
                 group.append((h, lease, start))
+                self._dequeued(h, "prefill")
             self.queue.extendleft(reversed(skipped))
             if not group:
                 return
@@ -1115,15 +1142,17 @@ class InferenceEngine:
             call_caches = DecodeCaches(blocks={
                 **{p: self.caches.blocks[p] for p in self._attn_pos},
                 **mamba_rows}, cross=None)
-            t0 = time.perf_counter()
-            logits, new_caches, counts = self._jit_prefill_paged(
-                self.params, {"tokens": jnp.asarray(batch_toks)},
-                call_caches, self.banks, jnp.asarray(tables),
-                jnp.asarray(starts), jnp.asarray(lengths),
-                has_prefix=has_prefix,
-                row_capacity=self._row_cap_prefill(bucket))
-            logits.block_until_ready()
-            dt = time.perf_counter() - t0
+            with span(self.tracer, "prefill", "engine", rows=G,
+                      bucket=bucket):
+                t0 = time.perf_counter()
+                logits, new_caches, counts = self._jit_prefill_paged(
+                    self.params, {"tokens": jnp.asarray(batch_toks)},
+                    call_caches, self.banks, jnp.asarray(tables),
+                    jnp.asarray(starts), jnp.asarray(lengths),
+                    has_prefix=has_prefix,
+                    row_capacity=self._row_cap_prefill(bucket))
+                logits.block_until_ready()
+                dt = time.perf_counter() - t0
             self.prefill_shapes.add((R, bucket))
             slots_arr = np.asarray(free[:G], np.int32)
             blocks = {p: new_caches.blocks[p] for p in self._attn_pos}
@@ -1168,18 +1197,20 @@ class InferenceEngine:
         G = len(group)
         if self.logits_hook is not None:
             self.logits_hook("prefill", logits, list(enumerate(group)))
-        amax = np.asarray(jnp.argmax(logits, -1), np.int32)
-        samp = self._gather_sampling_rows(
-            logits, [r for r, h in enumerate(group)
-                     if not h.sampler.greedy])
+        with span(self.tracer, "sample", "engine"):
+            amax = np.asarray(jnp.argmax(logits, -1), np.int32)
+            samp = self._gather_sampling_rows(
+                logits, [r for r, h in enumerate(group)
+                         if not h.sampler.greedy])
         counts_np = {k: np.asarray(v) for k, v in counts.items()}
         self.last_row_counts = counts_np
         self.last_counts = {k: v.sum(axis=1) if v.ndim == 3 else v
                             for k, v in counts_np.items()}
         row_valid = np.zeros(R, bool)
         row_valid[:G] = True
-        stall = self.backend.observe(counts_np, dt, prefill=True,
-                                     row_valid=row_valid)
+        with span(self.tracer, "observe", "residency"):
+            stall = self.backend.observe(counts_np, dt, prefill=True,
+                                         row_valid=row_valid)
         self._stall_clock += stall
         self._note_degraded(counts_np, list(enumerate(group)))
         for r, handle in enumerate(group):
@@ -1350,6 +1381,7 @@ class InferenceEngine:
             self.tracer.instant("preempt", cat="sched", rid=handle.id,
                                 slot=slot, pos=pos)
         self.queue.appendleft(handle)
+        self._queued(handle)
 
     def _maybe_preempt(self) -> None:
         """After admission: if a higher-class request is still queued while
@@ -1568,14 +1600,15 @@ class InferenceEngine:
         call_caches = DecodeCaches(
             blocks={p: self.caches.blocks[p] for p in self._attn_pos},
             cross=None)
-        t0 = time.perf_counter()
-        logits, new_caches, counts = self._jit_prefill_paged(
-            self.params, {"tokens": jnp.asarray(batch_toks)},
-            call_caches, self.banks, jnp.asarray(tables),
-            jnp.asarray(starts), jnp.asarray(lengths),
-            has_prefix=True, row_capacity=self._row_cap_prefill(bucket))
-        logits.block_until_ready()
-        dt = time.perf_counter() - t0
+        with span(self.tracer, "prefill", "engine", rows=G, bucket=bucket):
+            t0 = time.perf_counter()
+            logits, new_caches, counts = self._jit_prefill_paged(
+                self.params, {"tokens": jnp.asarray(batch_toks)},
+                call_caches, self.banks, jnp.asarray(tables),
+                jnp.asarray(starts), jnp.asarray(lengths),
+                has_prefix=True, row_capacity=self._row_cap_prefill(bucket))
+            logits.block_until_ready()
+            dt = time.perf_counter() - t0
         self.prefill_shapes.add((R, bucket))
         self.caches = DecodeCaches(
             blocks={**self.caches.blocks,
@@ -1587,8 +1620,9 @@ class InferenceEngine:
                             for k, v in counts_np.items()}
         row_valid = np.zeros(R, bool)
         row_valid[:G] = True
-        stall = self.backend.observe(counts_np, dt, prefill=True,
-                                     row_valid=row_valid)
+        with span(self.tracer, "observe", "residency"):
+            stall = self.backend.observe(counts_np, dt, prefill=True,
+                                         row_valid=row_valid)
         self._stall_clock += stall
         for _, h in group:
             h.stall_exposure_s += stall
@@ -1597,11 +1631,12 @@ class InferenceEngine:
         if self.logits_hook is not None:
             self.logits_hook("prefill", logits,
                              [(r, h) for r, (i, h) in enumerate(group)])
-        amax = np.asarray(jnp.argmax(logits, -1), np.int32)
-        samp = self._gather_sampling_rows(
-            logits, [r for r, (i, h) in enumerate(group)
-                     if not h.sampler.greedy and
-                     int(lengths[r]) == self._prompt_len(h)])
+        with span(self.tracer, "sample", "engine"):
+            amax = np.asarray(jnp.argmax(logits, -1), np.int32)
+            samp = self._gather_sampling_rows(
+                logits, [r for r, (i, h) in enumerate(group)
+                         if not h.sampler.greedy and
+                         int(lengths[r]) == self._prompt_len(h)])
         for r, (i, h) in enumerate(group):
             clen = int(lengths[r] - starts[r])
             h._chunk_pos = int(lengths[r])
@@ -1650,42 +1685,45 @@ class InferenceEngine:
         bursts when enabled), batch tier on the all-lo banks. One group —
         uniform-class traffic — is exactly the untiered engine. Returns
         the handles that finished this step."""
-        finished: List[RequestHandle] = []
-        if self._watchdog is not None:
-            # Scan BEFORE this step makes progress: a request that wedged
-            # during prior steps still carries its stale stamp here, and a
-            # cancelled promotion's slot is re-admittable this same step.
-            self._watchdog.scan(self)
-        ready = getattr(self.backend, "serving_ready", None)
-        if ready is not None and not ready():
-            # Streaming cold start: the residency ladder is still
-            # materializing — keep the backend's staging windows running
-            # and hold admission (requests queue; no forward may observe
-            # a partially materialized expert).
-            self.backend.tick()
+        with span(self.tracer, "step", "engine"):
+            finished: List[RequestHandle] = []
+            if self._watchdog is not None:
+                # Scan BEFORE this step makes progress: a request that wedged
+                # during prior steps still carries its stale stamp here, and a
+                # cancelled promotion's slot is re-admittable this same step.
+                self._watchdog.scan(self)
+            ready = getattr(self.backend, "serving_ready", None)
+            if ready is not None and not ready():
+                # Streaming cold start: the residency ladder is still
+                # materializing — keep the backend's staging windows running
+                # and hold admission (requests queue; no forward may observe
+                # a partially materialized expert).
+                with span(self.tracer, "tick", "residency"):
+                    self.backend.tick()
+                return finished
+            self._shed_expired()
+            self._admit(finished)
+            self._maybe_preempt()
+            self._advance_chunk_prefills(finished)
+            active = [(i, h) for i, h in enumerate(self.slots)
+                      if h is not None and h.state is RequestState.RUNNING]
+            if active:
+                groups = self.sched.decode_groups(active,
+                                                  self._spec is not None)
+                guard = len(groups) > 1 and bool(self._mamba_pos)
+                for kind, rows in groups:
+                    # The speculative round falls back to the single-token
+                    # step when no row has draft headroom (e.g. one token
+                    # remaining).
+                    if kind == "spec" and self._spec.round(rows, finished):
+                        continue
+                    self._decode_one(rows, finished, lo=(kind == "lo"),
+                                     guard_ssm=guard)
+            with span(self.tracer, "tick", "residency"):
+                self.backend.tick()
+            if self.obs is not None:
+                self._step_obs()
             return finished
-        self._shed_expired()
-        self._admit(finished)
-        self._maybe_preempt()
-        self._advance_chunk_prefills(finished)
-        active = [(i, h) for i, h in enumerate(self.slots)
-                  if h is not None and h.state is RequestState.RUNNING]
-        if active:
-            groups = self.sched.decode_groups(active,
-                                              self._spec is not None)
-            guard = len(groups) > 1 and bool(self._mamba_pos)
-            for kind, rows in groups:
-                # The speculative round falls back to the single-token
-                # step when no row has draft headroom (e.g. one token
-                # remaining).
-                if kind == "spec" and self._spec.round(rows, finished):
-                    continue
-                self._decode_one(rows, finished, lo=(kind == "lo"),
-                                 guard_ssm=guard)
-        self.backend.tick()
-        if self.obs is not None:
-            self._step_obs()
-        return finished
 
     def _step_obs(self) -> None:
         """Step-boundary observability: one ``step`` trace instant with the
@@ -1754,29 +1792,30 @@ class InferenceEngine:
         # alias: the decode jits donate the cache argument.)
         ssm_old = {p: jnp.array(self.caches.blocks[p])
                    for p in self._mamba_pos} if guard_ssm else None
-        t0 = time.perf_counter()
-        if self.pool is not None:
-            n = self.ecfg.max_slots
-            wblk = np.zeros(n, np.int32)     # vacant rows → trash block
-            woff = np.zeros(n, np.int32)
-            cows: List[Tuple[int, int]] = []
-            for i, h in active:
-                wblk[i], woff[i] = self._ensure_write(
-                    h.lease, int(self.pos[i]), cows)
-            self._apply_copies(cows)
-            logits, self.caches, counts = self._jit_decode_paged(
-                self.params, jnp.asarray(self.tokens),
-                jnp.asarray(self.pos), self.caches, banks,
-                jnp.asarray(row_valid),
-                jnp.asarray(self._block_tables()),
-                jnp.asarray(wblk), jnp.asarray(woff))
-        else:
-            logits, self.caches, counts = self._jit_decode(
-                self.params, jnp.asarray(self.tokens),
-                jnp.asarray(self.pos), self.caches, banks,
-                jnp.asarray(row_valid))
-        logits.block_until_ready()
-        dt = time.perf_counter() - t0
+        with span(self.tracer, "decode", "engine", rows=len(active)):
+            t0 = time.perf_counter()
+            if self.pool is not None:
+                n = self.ecfg.max_slots
+                wblk = np.zeros(n, np.int32)     # vacant rows → trash block
+                woff = np.zeros(n, np.int32)
+                cows: List[Tuple[int, int]] = []
+                for i, h in active:
+                    wblk[i], woff[i] = self._ensure_write(
+                        h.lease, int(self.pos[i]), cows)
+                self._apply_copies(cows)
+                logits, self.caches, counts = self._jit_decode_paged(
+                    self.params, jnp.asarray(self.tokens),
+                    jnp.asarray(self.pos), self.caches, banks,
+                    jnp.asarray(row_valid),
+                    jnp.asarray(self._block_tables()),
+                    jnp.asarray(wblk), jnp.asarray(woff))
+            else:
+                logits, self.caches, counts = self._jit_decode(
+                    self.params, jnp.asarray(self.tokens),
+                    jnp.asarray(self.pos), self.caches, banks,
+                    jnp.asarray(row_valid))
+            logits.block_until_ready()
+            dt = time.perf_counter() - t0
         if self.logits_hook is not None:
             self.logits_hook("decode", logits, list(active))
         if ssm_old is not None:
@@ -1789,8 +1828,9 @@ class InferenceEngine:
         self.last_counts = {k: v.sum(axis=1) if v.ndim == 3 else v
                             for k, v in counts_np.items()}
         self._note_dispatch(counts_np)
-        stall = self.backend.observe(counts_np, dt, prefill=False,
-                                     row_valid=row_valid)
+        with span(self.tracer, "observe", "residency"):
+            stall = self.backend.observe(counts_np, dt, prefill=False,
+                                         row_valid=row_valid)
         self._stall_clock += stall
         if stall:
             for _, h in active:
@@ -1801,27 +1841,28 @@ class InferenceEngine:
         self._tpot_tokens += len(active)
         self._tpot_ema = latency if self._tpot_ema == 0.0 else \
             0.9 * self._tpot_ema + 0.1 * latency
-        # Greedy fast path: only the (B,) device argmax crosses to host;
-        # full (·, V) logits rows ship only for requests that sample
-        # (device-gathered, so greedy neighbors stay off the transfer).
-        amax = np.asarray(jnp.argmax(logits, -1), np.int32)
-        samp = self._gather_sampling_rows(
-            logits, [i for i, h in active if not h.sampler.greedy])
         self._note_degraded(counts_np, active)
-        for i, handle in active:
-            tok = int(amax[i]) if i not in samp else \
-                handle.sampler.next_token(samp[i], len(handle.tokens))
-            handle.tokens.append(tok)
-            handle.step_times.append(latency)
-            if self._watchdog is not None:
-                handle.last_progress_s = self._now()
-            for k, v in counts_np.items():
-                if v.ndim == 3 and k in handle.expert_counts:
-                    handle.expert_counts[k] += v[:, i]
-            self.tokens[i] = tok
-            self.pos[i] += 1
-            if self._done(handle):
-                self._finish(handle, finished)
+        with span(self.tracer, "sample", "engine"):
+            # Greedy fast path: only the (B,) device argmax crosses to host;
+            # full (·, V) logits rows ship only for requests that sample
+            # (device-gathered, so greedy neighbors stay off the transfer).
+            amax = np.asarray(jnp.argmax(logits, -1), np.int32)
+            samp = self._gather_sampling_rows(
+                logits, [i for i, h in active if not h.sampler.greedy])
+            for i, handle in active:
+                tok = int(amax[i]) if i not in samp else \
+                    handle.sampler.next_token(samp[i], len(handle.tokens))
+                handle.tokens.append(tok)
+                handle.step_times.append(latency)
+                if self._watchdog is not None:
+                    handle.last_progress_s = self._now()
+                for k, v in counts_np.items():
+                    if v.ndim == 3 and k in handle.expert_counts:
+                        handle.expert_counts[k] += v[:, i]
+                self.tokens[i] = tok
+                self.pos[i] += 1
+                if self._done(handle):
+                    self._finish(handle, finished)
         self.counters["steps"] += 1
 
     def _note_degraded(self, counts_np: Dict[str, np.ndarray],

@@ -60,6 +60,7 @@ import numpy as np
 from repro.core.ver import ExpertBankQ
 from repro.models import spec_draft, spec_verify
 from repro.models.model import DecodeCaches
+from repro.obs.trace import span
 from repro.serving.sampler import (STREAM_ACCEPT, STREAM_BONUS,
                                    STREAM_RESIDUAL, RequestSampler,
                                    categorical, sampling_probs)
@@ -506,8 +507,9 @@ class SpecDecoder:
                 obs[kk] = (v * accept_mask[:, None, :, None]).sum(axis=0)
             else:                                  # aggregated fallback
                 obs[kk] = v.sum(axis=0)
-        stall = eng.backend.observe(obs, dt, prefill=False,
-                                    row_valid=row_valid)
+        with span(eng.tracer, "observe", "residency"):
+            stall = eng.backend.observe(obs, dt, prefill=False,
+                                        row_valid=row_valid)
         eng._stall_clock += stall
         if stall:
             for _, h in active:

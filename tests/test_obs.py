@@ -1,5 +1,6 @@
-"""Observability layer: flight recorder, metrics registry, trace cost model,
-stats-schema contract, stall attribution, and trace determinism.
+"""Observability layer: flight recorder and its spans, metrics registry,
+promotion report, stats-schema contract, stall attribution, named scopes
+in the decode program, and trace determinism.
 
 The schema tests are CONTRACTS, not snapshots: ``backend.stats()`` must
 return exactly ``STAT_KEYS + type(backend).STAT_EXTRAS`` and the engine adds
@@ -9,17 +10,22 @@ feature sits idle.
 """
 from __future__ import annotations
 
+import contextlib
 import json
+import re
 
+import jax
 import numpy as np
 import pytest
 
 from repro.obs import (FlightRecorder, MetricsRegistry, ObsConfig,
-                       Observability, costmodel, load_chrome_trace)
-from repro.serving import (OffloadConfig, Request, RequestStream, STAT_KEYS)
+                       Observability, load_chrome_trace, report, span)
+from repro.serving import (EngineConfig, InferenceEngine, OffloadConfig,
+                           Request, RequestStream, STAT_KEYS, make_backend)
 from repro.serving.backends import (DynaExqBackend, Fp16Backend,
                                     OffloadBackend, StaticPTQBackend)
 from repro.serving.engine import ENGINE_STAT_KEYS, LOAD_SNAPSHOT_KEYS
+from repro.serving.scheduler import SchedulerConfig
 
 
 # ---------------------------------------------------------------------------
@@ -122,59 +128,80 @@ def test_prometheus_exposition_and_jsonl_sink(tmp_path):
     m.close()                  # idempotent
 
 
-# ---------------------------------------------------------------------------
-# Cost model (trace replayer) on a synthetic trace
-# ---------------------------------------------------------------------------
+class _Annotations:
+    """Stand-in for ``jax.profiler.TraceAnnotation`` that records every
+    annotation entered: (name, args)."""
 
-def _synthetic_trace():
-    """A hand-built trace whose measured traffic matches the roofline
-    prediction exactly for the degenerate single-token case (every expert
-    distinct, k per token), so residuals must be 0."""
+    def __init__(self):
+        self.entered = []
+
+    def __call__(self, name, **args):
+        @contextlib.contextmanager
+        def enter():
+            self.entered.append((name, args))
+            yield
+        return enter()
+
+
+@pytest.fixture()
+def annotations(monkeypatch):
+    rec = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", rec)
+    return rec
+
+
+def test_span_writes_begin_end_with_parent_and_one_annotation(annotations):
+    t = iter(np.arange(0.0, 10.0, 1.0))
+    tr = FlightRecorder(clock=lambda: float(next(t)))
+    with tr.span("step", "engine") as outer:
+        with tr.span("decode", "engine", rows=3) as inner:
+            pass
+        with tr.span("tick", "residency", parent=99):
+            pass
+    b_out, b_in, e_in, b_tick, e_tick, e_out = tr.events()
+    assert [e.ph for e in tr.events()] == ["B", "B", "E", "B", "E", "E"]
+    assert (b_out.id, b_in.id) == (outer, inner) and outer != inner
+    assert b_out.args == {"parent": None}
+    assert b_in.args == {"rows": 3, "parent": outer}
+    assert b_tick.args["parent"] == 99          # an explicit parent wins
+    assert e_in.id == inner and e_out.id == outer
+    assert [e.ts for e in tr.events()] == [0.0, 1.0, 2.0, 3.0, 4.0, 5.0]
+    # One profiler annotation per span, named <cat>.<name>, with its id.
+    assert annotations.entered == [
+        ("engine.step", {"id": outer}),
+        ("engine.decode", {"id": inner, "rows": 3}),
+        ("residency.tick", {"id": b_tick.id})]
+    (pair,) = tr.spans("decode", cat="engine")
+    assert pair == (b_in, e_in)
+
+
+def test_span_without_recorder_is_one_shared_noop(annotations):
+    a = span(None, "step", "engine", rows=1)
+    b = span(None, "decode", "engine")
+    assert a is b
+    with a:
+        pass
+    assert annotations.entered == []
+
+
+def test_promotion_report_live_and_saved(tmp_path):
     t = iter(np.arange(0.0, 100.0, 0.25))
     tr = FlightRecorder(clock=lambda: float(next(t)))
-    tr.meta.update(moe_dispatch="padded", num_experts=8, top_k=2,
-                   lo_bytes=100, hi_bytes=400)
-    for step in range(5):
-        pub = 2 * 4            # 2 hi slots on each of 4 layers
-        tr.instant("moe_forward", cat="engine", routed=1 * 4 * 2, layers=4,
-                   active_hi=4, active_lo=4, active_host=0,
-                   published_hi=pub, prefill=0)
-    tr.instant("moe_forward", cat="engine", routed=64, layers=4,
-               active_hi=0, active_lo=0, active_host=0, published_hi=0,
-               prefill=1)      # prefill: excluded from decode folding
     sid = tr.next_id()
     tr.async_begin("promotion", sid, cat="residency", layer=0, expert=3)
     tr.async_end("promotion", sid, cat="residency", published=1)
     sid = tr.next_id()
     tr.async_begin("promotion", sid, cat="residency", layer=1, expert=5)
     tr.async_end("promotion", sid, cat="residency", published=0)
-    return tr
-
-
-def test_costmodel_fold_and_residuals(tmp_path):
-    tr = _synthetic_trace()
-    samples = costmodel.fold_steps(tr)
-    assert len(samples) == 5                       # prefill excluded
-    assert all(s["tokens"] == 1.0 for s in samples)
-    # padded: 4 layers × 8 experts × 100 B lo + 8 hi slots × 400 B
-    assert samples[0]["measured_bpt"] == 4 * 8 * 100 + 8 * 400
-    rep = costmodel.residual_report(tr)
-    assert rep["n_steps"] == 5
-    assert rep["max_abs_rel_residual"] == 0.0      # 1 token ⇒ model exact
-    prom = costmodel.promotion_report(tr)
+    rep = report(tr)
+    assert set(rep) == {"promotions"}
+    prom = rep["promotions"]
     assert prom["n_published"] == 1 and prom["n_cancelled"] == 1
     assert prom["publish_latency_p50_s"] == pytest.approx(0.25)
-    # Identical numbers replayed from the saved file.
+    # Identical numbers read back from the saved file.
     path = str(tmp_path / "t.trace.json")
     tr.save(path)
-    assert costmodel.report(path) == costmodel.report(tr)
-
-
-def test_costmodel_requires_meta():
-    tr = FlightRecorder(clock=lambda: 0.0)
-    tr.instant("moe_forward", cat="engine", routed=8, layers=4)
-    with pytest.raises(ValueError, match="metadata missing"):
-        costmodel.fold_steps(tr)
+    assert report(path) == rep
 
 
 # ---------------------------------------------------------------------------
@@ -262,13 +289,7 @@ def test_engine_emits_lifecycle_and_forward_events(engine_factory,
     assert {"submit", "admit", "finish", "step", "moe_forward"} <= names
     assert len(tr.instants("submit")) == len(handles)
     assert len(tr.instants("finish")) == len(handles)
-    # Engine meta carries everything the cost model needs.
-    assert all(k in tr.meta for k in costmodel.META_KEYS)
     assert tr.meta["backend"] == "dynaexq"
-    assert tr.meta["lo_bytes"] > 0 and tr.meta["hi_bytes"] > 0
-    # The replayer runs off the live recorder without error and sees steps.
-    rep = costmodel.report(tr)
-    assert rep["roofline"]["n_steps"] > 0
     # Metrics sampled at step cadence.
     snap = obs.metrics.snapshot()
     assert "engine_active_experts" in snap
@@ -334,6 +355,139 @@ def test_obs_none_leaves_engine_bare(engine_factory, serving_setup):
 
 
 # ---------------------------------------------------------------------------
+# Spans at the engine's layer boundaries
+# ---------------------------------------------------------------------------
+
+#: (cat, name) of every duration span the engine and the residency backend
+#: open; each nests under an ``engine.step``.
+ENGINE_SPANS = {("engine", "step"), ("sched", "admit"),
+                ("engine", "prefill"), ("engine", "decode"),
+                ("engine", "sample"), ("residency", "observe"),
+                ("residency", "tick"), ("residency", "promote")}
+
+
+def test_engine_spans_nest_under_step(engine_factory, serving_setup,
+                                      annotations):
+    cfg, _ = serving_setup
+    obs = Observability(ObsConfig(metrics=False))
+    eng, handles = _run(engine_factory, cfg, "dynaexq", obs, n=6,
+                        max_slots=2)
+    evs = obs.tracer.events()
+    begins = {e.id: e for e in evs if e.ph == "B"}
+    assert {(e.cat, e.name) for e in begins.values()} == ENGINE_SPANS
+    assert len(begins) == sum(e.ph == "E" for e in evs)
+    for b in begins.values():
+        if b.name == "step":
+            assert b.args["parent"] is None
+            continue
+        chain = [b]
+        while chain[-1].args["parent"] is not None:
+            chain.append(begins[chain[-1].args["parent"]])
+        assert (chain[-1].cat, chain[-1].name) == ("engine", "step"), \
+            [(c.cat, c.name) for c in chain]
+    decodes = [b for b in begins.values() if b.name == "decode"]
+    assert len(decodes) == eng.counters["steps"]
+    assert all(1 <= b.args["rows"] <= 2 for b in decodes)
+    prefills = [b for b in begins.values() if b.name == "prefill"]
+    assert len(prefills) == eng.counters["prefills"]
+    assert all(b.args["bucket"] in eng.buckets for b in prefills)
+    assert all(begins[b.args["parent"]].name == "admit" for b in prefills)
+    promos = [b for b in begins.values() if b.name == "promote"]
+    assert all(b.args["bytes"] > 0 for b in promos)
+    # Every span entered the profiler once, under <cat>.<name>.
+    assert sorted(n for n, _ in annotations.entered) == sorted(
+        f"{b.cat}.{b.name}" for b in begins.values())
+    # Each request queued once and left the queue once, for a prefill.
+    queue = obs.tracer.spans("queue", cat="sched")
+    assert sorted(b.id for b, _ in queue) == sorted(h.id for h in handles)
+    assert all(e.args["how"] == "prefill" for _, e in queue)
+
+
+def test_obs_none_serving_enters_no_annotation(engine_factory,
+                                               serving_setup, annotations):
+    cfg, _ = serving_setup
+    eng, handles = _run(engine_factory, cfg, "dynaexq", None)
+    assert all(h.tokens for h in handles)
+    assert annotations.entered == []
+
+
+def _queue_spans_engine(serving_setup, obs, **sched):
+    cfg, params = serving_setup
+    clone = jax.tree_util.tree_map(lambda x: x, params)
+    return InferenceEngine(
+        cfg, clone, make_backend("static", lo_bits=4),
+        EngineConfig(max_slots=2, max_len=64,
+                     scheduler=SchedulerConfig(**sched)), obs=obs)
+
+
+@pytest.mark.parametrize("path", ["paged", "chunked", "resume"])
+def test_queue_span_closes_once_per_admission(serving_setup, path):
+    """``sched.queue`` opens at submit (and again at a preemption's
+    requeue) and closes exactly once per admission: a prefill group, a
+    chunked admission or a resume."""
+    cfg, _ = serving_setup
+    obs = Observability(ObsConfig(metrics=False))
+    rng = np.random.default_rng(3)
+    if path == "chunked":
+        eng = _queue_spans_engine(serving_setup, obs, prefill_chunk=32)
+        plen = 48
+    else:
+        eng = _queue_spans_engine(serving_setup, obs)
+        plen = 12
+    handles = [eng.submit(Request(
+        tokens=rng.integers(0, cfg.vocab_size, size=plen),
+        max_new_tokens=5)) for _ in range(3)]
+    if path == "resume":
+        eng.step()
+        victim = next(h for h in handles if h.state.value == "running")
+        eng.preempt(victim)
+    eng.drain()
+    assert all(h.state.value == "finished" for h in handles)
+    tr = obs.tracer
+    queue = tr.spans("queue", cat="sched")
+    opened = [e for e in tr.events() if e.name == "queue" and e.ph == "b"]
+    assert len(queue) == len(opened)           # none left open
+    how = {}
+    for _, e in queue:
+        how.setdefault(e.id, []).append(e.args["how"])
+    first = {"paged": "prefill", "chunked": "chunk", "resume": "prefill"}
+    assert set(how) == {h.id for h in handles}
+    for h in handles:
+        want = [first[path]] + (["resume"] if h.preempts else [])
+        assert how[h.id] == want
+    assert sum(h.preempts for h in handles) == (path == "resume")
+
+
+# ---------------------------------------------------------------------------
+# Named scopes in the decode program
+# ---------------------------------------------------------------------------
+
+def _op_list(text):
+    """The program's ops in order, locations left out."""
+    return re.findall(r"\b(?:stablehlo|chlo|func|tpu|sdy|mhlo)\.[a-z_]+",
+                      re.sub(r"^#loc.*$", "", text, flags=re.M))
+
+
+def test_decode_program_scopes_change_no_op(engine_factory, monkeypatch):
+    eng = engine_factory("dynaexq", max_slots=2)
+    scoped = eng.decode_program_text()
+    for scope in ("attn/", "kv_write/", "moe/router/", "moe/dispatch/",
+                  "moe/ffn/", "moe/combine/", "lm_head/"):
+        assert f'"{scope}' in scoped or f'/{scope}' in scoped, scope
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    jax.clear_caches()          # trace again, without the scopes
+    try:
+        bare = eng.decode_program_text()
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    assert "moe/ffn" not in bare and "attn/" not in bare
+    ops = _op_list(scoped)
+    assert len(ops) > 100 and ops == _op_list(bare)
+
+
+# ---------------------------------------------------------------------------
 # Trace determinism under the virtual clock
 # ---------------------------------------------------------------------------
 
@@ -361,6 +515,11 @@ def test_virtual_clock_replay_traces_byte_identical(engine_factory,
     a, b = one("a"), one("b")
     assert len(a) > 200
     assert a == b
+    # The engine's duration and queue spans are part of what replays
+    # byte-identically.
+    phases = {(e["ph"], e["name"]) for e in json.loads(a)["traceEvents"]}
+    assert {("B", "step"), ("E", "decode"), ("b", "queue"),
+            ("e", "queue")} <= phases
     # And the events are genuinely virtual-clock stamped: the first event
     # sits near t=0, not at perf_counter's epoch.
     first = json.loads(a)["traceEvents"][0]
