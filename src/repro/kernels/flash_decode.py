@@ -8,11 +8,11 @@ map (q head → kv head), so kv tiles are fetched once per kv head group.
 Grid: (B, H, S/bs), S innermost/sequential with running (m, l, acc) scratch.
 
 ``flash_decode_paged`` is the gather-by-block-table variant for the paged
-KV pool (``repro.serving.kvpool``): K/V live as (N, Hkv, bt, hd) physical
-blocks — the ``repro.models.layers.PagedKVCache`` layout — and each
-sequence's (B, nb) block table rides in as a scalar-prefetch argument, so
-the BlockSpec index map DMAs exactly the blocks the row owns — no
-materialized logical copy of the cache.
+KV pool (``repro.serving.kvpool``): K/V come as (N, Hkv, bt, hd) physical
+blocks — the ``repro.models.layers.PagedKVCache`` pool, head-major — and
+each sequence's (B, nb) block table rides in as a scalar-prefetch
+argument, so the BlockSpec index map DMAs exactly the blocks the row owns
+— no materialized logical copy of the cache.
 """
 from __future__ import annotations
 
@@ -128,9 +128,9 @@ def _fd_paged_kernel(table_ref, q_ref, k_ref, v_ref, valid_ref, o_ref,
 def flash_decode_paged(q: jax.Array, k: jax.Array, v: jax.Array,
                        table: jax.Array, valid: jax.Array,
                        *, interpret: bool = False) -> jax.Array:
-    """Paged flash decode over the pool's own layout: q: (B, H, hd); k/v:
-    (N, Hkv, bt, hd) physical block pools (exactly
-    ``repro.models.layers.PagedKVCache``, one superblock slice); table:
+    """Paged flash decode over the pool's blocks: q: (B, H, hd); k/v:
+    (N, Hkv, bt, hd) physical block pools (``repro.models.layers.
+    PagedKVCache``, one superblock slice, head-major); table:
     (B, nb) int32 physical block ids per logical block (-1 = unallocated,
     routed to block 0 — mask those slots out via ``valid``); valid:
     (B, nb·bt) bool over the logical view. Returns (B, H, hd), numerically

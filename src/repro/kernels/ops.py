@@ -191,7 +191,11 @@ def flash_decode_paged_op(q: jax.Array, k: jax.Array, v: jax.Array,
                           table: jax.Array, valid: jax.Array,
                           interpret: bool | None = None) -> jax.Array:
     """Block-table flash decode over the paged KV pool (see
-    ``flash_decode_paged``); k/v are (N, Hkv, bt, hd) physical blocks —
-    the ``PagedKVCache`` layout, one superblock slice."""
+    ``flash_decode_paged``); k/v are (N, bt, Hkv·hd) physical blocks —
+    the ``PagedKVCache`` layout, one superblock slice — which this wrapper
+    transposes to the kernel's (N, Hkv, bt, hd)."""
     interpret = _interpret_default() if interpret is None else interpret
+    N, bt, hd = k.shape[0], k.shape[1], q.shape[-1]
+    k = k.reshape(N, bt, -1, hd).transpose(0, 2, 1, 3)
+    v = v.reshape(N, bt, -1, hd).transpose(0, 2, 1, 3)
     return flash_decode_paged(q, k, v, table, valid, interpret=interpret)

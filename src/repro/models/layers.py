@@ -80,16 +80,23 @@ def init_kv_cache(batch: int, capacity: int, cfg: AttnConfig,
 
 
 class PagedKVCache(NamedTuple):
-    """Physical KV block pool, laid out (N, Hkv, bt, hd): ``N`` fixed-size
-    blocks of ``bt`` cache positions each, shared by every request. A
-    request's logical cache is named by a *block table* row ((nb,) int32 of
-    physical block ids, -1 = unallocated): gathering the table recovers the
-    exact (Hkv, nb·bt, hd) head-major view the dense ``KVCache`` stores per
-    batch row, so both layouts run the same attention math. Block 0 is the
-    pool's trash block (vacant-row writes land there; see
-    ``repro.serving.kvpool``)."""
-    k: jax.Array  # (N, Hkv, bt, hd)
-    v: jax.Array  # (N, Hkv, bt, hd)
+    """Physical KV block pool, laid out (N, bt, Hkv·hd): ``N`` fixed-size
+    blocks of ``bt`` cache positions each, shared by every request; one
+    position's K (or V) for every KV head is one row. A request's logical
+    cache is named by a *block table* row ((nb,) int32 of physical block
+    ids, -1 = unallocated): gathering the table recovers the exact
+    (Hkv, nb·bt, hd) head-major view the dense ``KVCache`` stores per batch
+    row, so both layouts run the same attention math. Block 0 is the pool's
+    trash block (vacant-row writes land there; see
+    ``repro.serving.kvpool``).
+
+    A row holds all heads so that it is at least 128 wide: a TPU lays out
+    an array whose last dim is narrower (``hd`` = 64) with its block dim
+    innermost, and then every gather of blocks, and every write of a
+    position, first relayouts the pool. Here a block is whole in the
+    device layout and a position's write is one row."""
+    k: jax.Array  # (N, bt, Hkv·hd)
+    v: jax.Array  # (N, bt, Hkv·hd)
 
     @property
     def n_blocks(self) -> int:
@@ -97,28 +104,37 @@ class PagedKVCache(NamedTuple):
 
     @property
     def block_tokens(self) -> int:
-        return self.k.shape[2]
+        return self.k.shape[1]
 
 
 def init_paged_kv_cache(n_blocks: int, block_tokens: int, cfg: AttnConfig,
                         dtype=jnp.bfloat16) -> PagedKVCache:
-    shape = (n_blocks, cfg.n_kv_heads, block_tokens, cfg.head_dim)
+    shape = (n_blocks, block_tokens, cfg.n_kv_heads * cfg.head_dim)
     return PagedKVCache(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
 
 
-def paged_view(cache: PagedKVCache, table: jax.Array
+def paged_view(cache: PagedKVCache, table: jax.Array, hd: int,
+               layer: Optional[jax.Array] = None
                ) -> tuple[jax.Array, jax.Array]:
     """Gather-by-block-table: (B, nb) table → (B, Hkv, nb·bt, hd) logical
     K/V views (slot order = logical cache order). Unallocated (-1) entries
-    read the trash block; callers mask those slots out of attention."""
+    read the trash block; callers mask those slots out of attention. With
+    ``layer`` the cache is the stacked (nsb, N, bt, Hkv·hd) pool and the
+    blocks are gathered from that layer where they lie."""
     B, nb = table.shape
-    idx = jnp.clip(table, 0)
-    k = cache.k[idx]                           # (B, nb, Hkv, bt, hd)
-    v = cache.v[idx]
-    Hkv, bt, hd = k.shape[2], k.shape[3], k.shape[4]
-    k = jnp.moveaxis(k, 2, 1).reshape(B, Hkv, nb * bt, hd)
-    v = jnp.moveaxis(v, 2, 1).reshape(B, Hkv, nb * bt, hd)
-    return k, v
+    at = jnp.clip(table, 0)
+    if layer is not None:
+        at = (layer, at)
+    k = cache.k[at]                            # (B, nb, bt, Hkv·hd)
+    v = cache.v[at]
+    F = k.shape[-1]
+    # (B, C, Hkv·hd) → (B, Hkv·hd, C) → (B, Hkv, hd, C) → (B, Hkv, C, hd):
+    # a v5e compiles this to one transpose of the gathered rows into the
+    # C-innermost layout the attention dots read, without first splitting
+    # the rows into hd-wide (lane-padded) pieces.
+    k = jnp.swapaxes(k.reshape(B, -1, F), 1, 2).reshape(B, F // hd, hd, -1)
+    v = jnp.swapaxes(v.reshape(B, -1, F), 1, 2).reshape(B, F // hd, hd, -1)
+    return jnp.swapaxes(k, 2, 3), jnp.swapaxes(v, 2, 3)
 
 
 def init_attention(key, d_model: int, cfg: AttnConfig) -> Param:
@@ -377,14 +393,17 @@ def attention_decode(p: Param, cfg: AttnConfig, x: jax.Array, pos: jax.Array,
 
 
 def attention_decode_paged(p: Param, cfg: AttnConfig, x: jax.Array,
-                           pos: jax.Array, cache: PagedKVCache,
-                           table: jax.Array, write_blk: jax.Array,
-                           write_off: jax.Array
+                           pos: jax.Array, pool: PagedKVCache,
+                           layer: jax.Array, table: jax.Array,
+                           write_blk: jax.Array, write_off: jax.Array
                            ) -> tuple[jax.Array, PagedKVCache]:
-    """One-token decode against the paged pool. ``table``: (B, nb) block
+    """One-token decode against the stacked (nsb, N, bt, Hkv·hd) pool, in
+    place: the layer's new K/V row is written at ``(layer, write_blk,
+    write_off)`` and its blocks are gathered from ``layer`` where they lie,
+    so no layer-sized slice of the pool is made. ``table``: (B, nb) block
     tables; ``write_blk``/``write_off``: (B,) physical block + in-block
     offset for each row's write (COW already resolved host-side — the
-    engine routes vacant rows to the trash block). After the scatter the
+    engine routes vacant rows to the trash block). After the write the
     gathered logical view equals the dense cache row bit for bit, so decode
     shares ``_attend_cache`` with the contiguous path."""
     B = x.shape[0]
@@ -392,11 +411,12 @@ def attention_decode_paged(p: Param, cfg: AttnConfig, x: jax.Array,
     pos_b = jnp.broadcast_to(pos, (B,)) if pos.ndim == 0 else pos
     q, k, v = _project_qkv(p, cfg, x, pos_b[:, None])
     with jax.named_scope("kv_write"):
-        cache = PagedKVCache(cache.k.at[write_blk, :, write_off].set(k[:, 0]),
-                             cache.v.at[write_blk, :, write_off].set(v[:, 0]))
-    k_all, v_all = paged_view(cache, table)
+        at = (layer, write_blk, write_off)
+        pool = PagedKVCache(pool.k.at[at].set(k.reshape(B, -1)),
+                            pool.v.at[at].set(v.reshape(B, -1)))
+    k_all, v_all = paged_view(pool, table, cfg.head_dim, layer)
     out = _attend_cache(q, k_all, v_all, pos_b, cfg)
-    return out @ p["wo"], cache
+    return out @ p["wo"], pool
 
 
 def attention_prefill_paged(p: Param, cfg: AttnConfig, x: jax.Array,
@@ -431,7 +451,7 @@ def attention_prefill_paged(p: Param, cfg: AttnConfig, x: jax.Array,
     if has_prefix:
         # Prefix view BEFORE the suffix write (the data dependency keeps
         # the gather ordered ahead of the scatter inside one jit).
-        pk, pv = paged_view(cache, table)                # (B, Hkv, C, hd)
+        pk, pv = paged_view(cache, table, cfg.head_dim)  # (B, Hkv, C, hd)
 
     with jax.named_scope("kv_write"):
         # ---- scatter suffix K/V: slot s takes the row's largest real position
@@ -441,16 +461,16 @@ def attention_prefill_paged(p: Param, cfg: AttnConfig, x: jax.Array,
         last = lengths[:, None] - 1 - jnp.mod(lengths[:, None] - 1 - idx, C)
         own = (last >= start[:, None]) & (lengths[:, None] > start[:, None])
         src = jnp.clip(last - start[:, None], 0, S - 1)
-        kc = k.transpose(0, 2, 1, 3)                          # (B, Hkv, S, hd)
-        vc = v.transpose(0, 2, 1, 3)
-        gk = jnp.take_along_axis(kc, src[:, None, :, None], axis=2)
-        gv = jnp.take_along_axis(vc, src[:, None, :, None], axis=2)
+        kc = k.reshape(B, S, -1)                          # (B, S, Hkv·hd)
+        vc = v.reshape(B, S, -1)
+        gk = jnp.take_along_axis(kc, src[:, :, None], axis=1)
+        gv = jnp.take_along_axis(vc, src[:, :, None], axis=1)
         blk = jnp.take_along_axis(table, jnp.broadcast_to(idx // bt, (B, C)),
                                   axis=1)
         phys = jnp.where(own, jnp.clip(blk, 0), 0)
         offs = jnp.broadcast_to(idx % bt, (B, C))
-        new_k = cache.k.at[phys, :, offs].set(gk.transpose(0, 2, 1, 3))
-        new_v = cache.v.at[phys, :, offs].set(gv.transpose(0, 2, 1, 3))
+        new_k = cache.k.at[phys, offs].set(gk)
+        new_v = cache.v.at[phys, offs].set(gv)
 
     # ---- suffix queries over [cached prefix ⊕ suffix] ------------------
     qpos = positions[:, :, None]                          # (B, S, 1)

@@ -179,7 +179,7 @@ def init_paged_caches(cfg: ArchConfig, batch: int, max_len: int,
                       block_tokens: int, n_blocks: int,
                       dtype=jnp.bfloat16) -> DecodeCaches:
     """Decode caches for the paged engine: attention positions hold ONE
-    shared (nsb, N, Hkv, bt, hd) physical block pool (batch-independent —
+    shared (nsb, N, bt, Hkv·hd) physical block pool (batch-independent —
     requests lease blocks out of it via block tables), while mamba
     positions keep their per-slot recurrent state rows (O(1) per slot, so
     paging them buys nothing)."""
@@ -253,8 +253,10 @@ def _block_step(bp: Dict, cfg: ArchConfig, pos: int, kind: str, x: jax.Array,
     and optional per-row router counts (see ``prefill``/``decode_step``).
     ``paged`` switches attention positions to the block-table path
     (gather/scatter against the shared ``PagedKVCache`` pool): a dict with
-    ``table`` (B, nb) and either ``write_blk``/``write_off`` (decode) or
-    ``start``/``has_prefix`` (prefill). Mamba positions are unaffected —
+    ``table`` (B, nb) and either ``layer``/``write_blk``/``write_off``
+    (decode: ``cache`` is then the whole stacked pool, read and written at
+    ``layer``) or ``start``/``has_prefix`` (prefill: ``cache`` is the
+    layer's slice). Mamba positions are unaffected —
     their per-slot state is not paged. ``row_capacity``/``moe_dispatch``
     select the MoE drop rule and token layout (see ``moe.moe_apply``).
     Returns (x, cache, counts) where counts is (E,) or (n_rows, E)."""
@@ -275,7 +277,7 @@ def _block_step(bp: Dict, cfg: ArchConfig, pos: int, kind: str, x: jax.Array,
                 else:
                     attn_out, cache = L.attention_decode_paged(
                         bp["attn"], cfg.attn, h, pos_idx, cache,
-                        paged["table"], paged["write_blk"],
+                        paged["layer"], paged["table"], paged["write_blk"],
                         paged["write_off"])
             elif prefill:
                 attn_out, cache = L.attention_prefill(
@@ -610,7 +612,13 @@ def decode_step_paged(params: Dict, cfg: ArchConfig, token: jax.Array,
     ``write_off`` ((B,) int32) name each row's pre-resolved physical write
     target (vacant rows point at the trash block). Semantics otherwise
     identical to ``decode_step`` — the gathered logical view equals the
-    dense per-slot cache bit for bit."""
+    dense per-slot cache bit for bit.
+
+    The stacked pools ride in the layer scan's carry: each layer writes its
+    one new entry per row and gathers its blocks in place at the scan's
+    layer index, so no pool-sized array enters or leaves the scan and the
+    (donated) pools come back where they were. Mamba positions scan their
+    per-slot state as ``xs``/``ys``."""
     sb = cfg.superblock_or_default()
     x = params["embed"][token][:, None, :]  # (B, 1, d)
     B = x.shape[0]
@@ -618,36 +626,48 @@ def decode_step_paged(params: Dict, cfg: ArchConfig, token: jax.Array,
     token_valid = None if row_valid is None \
         else jnp.asarray(row_valid, bool).reshape(-1)
     n_rows = B if per_row_counts else None
-    paged = {"table": block_table, "write_blk": write_blk,
-             "write_off": write_off}
+    pools = {k: c for k, c in caches.blocks.items()
+             if isinstance(c, L.PagedKVCache)}
+    state = {k: c for k, c in caches.blocks.items() if k not in pools}
 
-    def sb_body(x, xs):
+    def sb_body(carry, xs):
+        x, pools = carry
         if bank is not None:
-            bp_sliced, cache_sliced, bank_sliced = xs
+            layer, bp_sliced, state_sliced, bank_sliced = xs
         else:
-            bp_sliced, cache_sliced = xs
+            layer, bp_sliced, state_sliced = xs
             bank_sliced = None
-        counts_out, new_caches = {}, {}
+        paged = {"layer": layer, "table": block_table,
+                 "write_blk": write_blk, "write_off": write_off}
+        pools = dict(pools)
+        counts_out, new_state = {}, {}
         for pos, kind in enumerate(sb):
-            x, c, counts = _block_step(bp_sliced[str(pos)], cfg, pos, kind, x,
-                                       cache_sliced[str(pos)], pos_idx, cap,
+            key = str(pos)
+            cache = pools[key] if key in pools else state_sliced[key]
+            x, c, counts = _block_step(bp_sliced[key], cfg, pos, kind, x,
+                                       cache, pos_idx, cap,
                                        bank_sliced, None,
                                        prefill=False,
                                        token_valid=token_valid,
                                        n_rows=n_rows, paged=paged,
                                        row_capacity=row_capacity,
                                        moe_dispatch=moe_dispatch)
-            new_caches[str(pos)] = c
+            if key in pools:
+                pools[key] = c
+            else:
+                new_state[key] = c
             if counts is not None:
-                counts_out[str(pos)] = counts
-        return x, (new_caches, counts_out)
+                counts_out[key] = counts
+        return (x, pools), (new_state, counts_out)
 
-    xs = (params["blocks"], caches.blocks)
+    nsb = cfg.n_superblocks()
+    xs = (jnp.arange(nsb, dtype=jnp.int32), params["blocks"], state)
     if bank is not None:
         xs = xs + (bank,)
-    x, (new_blocks, counts) = _scan(sb_body, x, xs)
+    (x, pools), (new_state, counts) = _scan(sb_body, (x, pools), xs)
     logits = _lm_logits(params, cfg, x)[:, 0]
-    return logits, DecodeCaches(blocks=new_blocks, cross=None), counts
+    return logits, DecodeCaches(blocks={**new_state, **pools}, cross=None), \
+        counts
 
 
 # --------------------------------------------------------------------------

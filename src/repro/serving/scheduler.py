@@ -241,7 +241,7 @@ class SlotSnapshot:
     the last window for sliding-window rings).
 
     Paged mode stores per-position KV lanes (``attn_lanes[leaf]``:
-    ``(1, n_span, nsb, Hkv, hd)`` — the `_gather_paged_lanes` layout);
+    ``(1, n_span, nsb, Hkv·hd)`` — the `_gather_paged_lanes` layout);
     dense mode stores whole cache rows. Mamba rows are whole-state either
     way (recurrent state has no per-position structure)."""
     pos: int

@@ -160,17 +160,18 @@ def _restore_dense_slots(blocks: Dict, snap: Dict, slots, mask):
 
 @jax.jit
 def _gather_paged_lanes(blocks: Dict, blk, off):
-    """blocks: {pos: PagedKVCache((nsb, N, Hkv, bt, hd))}; blk/off: (B, W)
-    physical lanes → snapshots (B, W, nsb, Hkv, hd) per leaf."""
-    return jax.tree_util.tree_map(lambda a: a[:, blk, :, off], blocks)
+    """blocks: {pos: PagedKVCache((nsb, N, bt, Hkv·hd))}; blk/off: (B, W)
+    physical lanes → snapshots (B, W, nsb, Hkv·hd) per leaf."""
+    return jax.tree_util.tree_map(
+        lambda a: jnp.moveaxis(a[:, blk, off], 0, 2), blocks)
 
 
 @jax.jit
 def _restore_paged_lanes(blocks: Dict, snap: Dict, blk, off, mask):
     def one(a, s):
-        cur = a[:, blk, :, off]
-        vals = jnp.where(mask[:, :, None, None, None], s, cur)
-        return a.at[:, blk, :, off].set(vals)
+        cur = a[:, blk, off]                       # (nsb, B, W, Hkv·hd)
+        vals = jnp.where(mask[:, :, None], jnp.moveaxis(s, 2, 0), cur)
+        return a.at[:, blk, off].set(vals)
     return jax.tree_util.tree_map(one, blocks, snap)
 
 

@@ -137,3 +137,88 @@ def engine_factory(serving_setup):
                                             max_len=max_len), obs=obs)
 
     return build
+
+
+@pytest.fixture(scope="session")
+def paged_decode_program_check():
+    """``check(cfg, sharding=None, ...)``: compile the paged engine's
+    decode step (``_decode_paged_jit``) from shapes, for ``sharding``'s
+    device when given, and assert that the stacked KV pools stay out of the
+    layer scan's ``xs``/``ys``: no instruction of a loop (its body and all
+    it calls) makes an array of one layer's pool shape, no whole pool is
+    copied anywhere, and each returned pool aliases its donated input."""
+    import re
+
+    from repro.models import init_paged_caches, init_params
+    from repro.models.layers import PagedKVCache
+    from repro.serving.engine import _decode_paged_jit
+
+    def check(cfg, sharding=None, batch=2, max_len=32, block_tokens=4,
+              n_blocks=41):
+        import jax.numpy as jnp
+
+        def spec(a):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+        params = jax.eval_shape(lambda k: init_params(k, cfg),
+                                jax.random.PRNGKey(0))
+        caches = jax.eval_shape(lambda: init_paged_caches(
+            cfg, batch, max_len, block_tokens, n_blocks))
+        i32 = jax.ShapeDtypeStruct((batch,), jnp.int32)
+        args = jax.tree_util.tree_map(spec, (
+            params, i32, i32, caches, None,
+            jax.ShapeDtypeStruct((batch,), jnp.bool_),
+            jax.ShapeDtypeStruct((batch, max_len // block_tokens),
+                                 jnp.int32), i32, i32))
+        lowered = _decode_paged_jit.lower(*args, cfg=cfg,
+                                          capacity_factor=2.0)
+        hlo = lowered.compile().as_text()
+
+        comps = {}
+        for block in hlo.split("\n\n"):
+            head = block.strip().split("\n", 1)[0].split()
+            if head:
+                name = head[1] if head[0] == "ENTRY" else head[0]
+                comps[name.lstrip("%")] = block
+
+        def reach(name, seen):
+            if name in comps and name not in seen:
+                seen.add(name)
+                for callee in re.findall(
+                        r"(?:calls|to_apply|body|condition|"
+                        r"branch_computations)=\{?%?([\w.\-]+)",
+                        comps[name]):
+                    reach(callee, seen)
+            return seen
+
+        loops = set()
+        for body in re.findall(r" while\(.*?body=%?([\w.\-]+)", hlo):
+            loops |= reach(body, set())
+        assert loops
+        leaves = [a for c in caches.blocks.values()
+                  if isinstance(c, PagedKVCache) for a in c]
+        assert leaves
+        layer = {",".join(map(str, s)) for a in leaves
+                 for s in (a.shape[1:], (1,) + a.shape[1:])}
+        made = re.compile(r"= \w+\[(%s)\]" % "|".join(map(re.escape, layer)))
+        found = [ln.strip()[:160] for c in loops
+                 for ln in comps[c].splitlines() if made.search(ln)]
+        assert not found, found
+        whole = ",".join(map(str, leaves[0].shape))
+        assert not re.findall(r".*= \w+\[%s\]\S* copy\(.*" % whole, hlo)
+
+        # flattened argument and output positions of the pool leaves
+        flat = jax.tree_util.tree_leaves(caches)
+        first = len(jax.tree_util.tree_leaves(args[:3]))
+        ids = {first + i for i, a in enumerate(flat)
+               if any(a is leaf for leaf in leaves)}
+        out = jax.tree_util.tree_leaves(lowered.out_info)
+        outs = {i for i, a in enumerate(out) if a.shape == leaves[0].shape}
+        alias = hlo.split("\n", 1)[0].split("input_output_alias=", 1)[1]
+        alias = alias.split("entry_computation_layout", 1)[0]
+        pairs = {(int(o), int(p)) for o, p in
+                 re.findall(r"\{(\d+)\}: \((\d+),", alias)}
+        assert len(ids) == len(outs) == len(leaves)
+        assert {(o, p) for o, p in pairs if p in ids} == \
+            set(zip(sorted(outs), sorted(ids))), (alias, ids, outs)
+
+    return check

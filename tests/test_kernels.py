@@ -89,7 +89,7 @@ def test_flash_decode_matches_model_attention():
 def test_flash_decode_paged_matches_gathered_dense(h, hkv, bt, nb):
     """Block-table flash decode == dense flash decode over the logical view
     the (scrambled, partially unallocated) tables gather — and it consumes
-    the ``PagedKVCache`` (N, Hkv, bt, hd) pool layout directly, matching
+    the ``PagedKVCache`` (N, bt, Hkv·hd) pool layout, matching
     ``layers.paged_view``."""
     from repro.models import layers as L
     from repro.models.config import AttnConfig
@@ -111,7 +111,7 @@ def test_flash_decode_paged_matches_gathered_dense(h, hkv, bt, nb):
     valid[0, :S - bt - 3] = True        # stays clear of the -1 block
     valid[1, :S - 1] = True
     # dense reference over the same logical view the model gathers
-    k_log, v_log = L.paged_view(pool, jnp.asarray(table))  # (B,Hkv,S,hd)
+    k_log, v_log = L.paged_view(pool, jnp.asarray(table), hd)  # (B,Hkv,S,hd)
     want = flash_decode_op(q, jnp.moveaxis(k_log, 1, 2),
                            jnp.moveaxis(v_log, 1, 2),
                            jnp.asarray(valid), bs=bt)

@@ -8,12 +8,17 @@ the gathered dense reference."""
 import dataclasses
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import get_config
 from repro.core import ControllerConfig
-from repro.models import init_params
+from repro.models import (attn_logical_capacity, decode_step,
+                          decode_step_paged, init_caches, init_paged_caches,
+                          init_params)
+from repro.models import layers as L
+from repro.models.model import DecodeCaches
 from repro.serving import (EngineConfig, InferenceEngine, Request,
                            RequestStream, make_backend, make_prompts)
 from repro.serving.engine import _prefill_paged_jit
@@ -123,6 +128,133 @@ def test_paged_matches_dense_mamba_and_mixed_stack(serving_setup):
     assert eng.pool is not None and eng.trie is None
     assert dense == _serve(eng, prompts, (3, 3, 3))
     eng.pool.check_invariants()
+
+
+# ---------------------------------------------------------------------------
+# Model level: one paged decode step == one dense decode step, bit for bit
+# ---------------------------------------------------------------------------
+
+def _stack(serving_setup, stack):
+    """(cfg, params, max_len, block_tokens) of a full-attention stack, a
+    sliding-window ring (capacity 8 in two blocks, so decode wraps) and a
+    mixed mamba/attention stack of two superblocks."""
+    cfg, params = serving_setup
+    if stack == "full":
+        return cfg, params, 32, 4
+    if stack == "sliding":
+        cfg = dataclasses.replace(
+            cfg, name="granite-sw8",
+            attn=dataclasses.replace(cfg.attn, sliding_window=8))
+        return cfg, params, 32, 4
+    cfg = dataclasses.replace(get_config("jamba-v0_1-52b", reduced=True),
+                              n_layers=16)
+    return cfg, init_params(jax.random.PRNGKey(0), cfg), 16, 4
+
+
+def _random_like(tree, seed):
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
+    return jax.tree_util.tree_unflatten(treedef, [
+        jax.random.normal(k, a.shape, jnp.float32).astype(a.dtype)
+        for k, a in zip(keys, leaves)])
+
+
+def _row_view(pool, table_row, slots, hkv):
+    """Row's K (or V) at ``slots`` read from a (nsb, N, bt, Hkv·hd) pool
+    leaf by hand: (nsb, Hkv, len(slots), hd)."""
+    bt = pool.shape[2]
+    rows = np.stack([pool[:, table_row[s // bt], s % bt] for s in slots], 1)
+    return rows.reshape(rows.shape[:2] + (hkv, -1)).transpose(0, 2, 1, 3)
+
+
+@pytest.mark.parametrize("stack", ["full", "sliding", "mamba"])
+def test_decode_step_paged_matches_dense_bit_for_bit(serving_setup, stack):
+    """Two chained ``decode_step_paged`` steps against a scrambled pool give
+    the dense ``decode_step``'s logits, cache rows and recurrent state bit
+    for bit. Row 0 is deep in its table (its ring wraps in the sliding
+    stack); row 1's table ends in -1 entries and its second step lands in
+    a freshly allocated block; row 2 is vacant and writes the trash block.
+    The pool changes at the live rows' write targets and the trash
+    block's, nowhere else."""
+    cfg, params, max_len, bt = _stack(serving_setup, stack)
+    B, N = 3, 40
+    dense = init_caches(cfg, B, max_len)
+    dense = DecodeCaches(blocks=_random_like(dense.blocks, 1), cross=None)
+    paged = init_paged_caches(cfg, B, max_len, bt, N)
+    blocks = _random_like(paged.blocks, 2)
+    attn = [k for k, c in blocks.items() if isinstance(c, L.PagedKVCache)]
+    assert attn
+    C = dense.blocks[attn[0]].k.shape[3]
+    nb, hkv = C // bt, cfg.attn.n_kv_heads
+    assert attn_logical_capacity(cfg, max_len, bt) == C
+    rng = np.random.default_rng(0)
+    free = list(rng.permutation(np.arange(1, N)))
+    pos = np.array([C + 3, 3, 0] if stack == "sliding" else [14, 7, 0],
+                   np.int32)
+    table = np.full((B, nb), -1, np.int32)
+    for b in (0, 1):
+        for j in range(min(nb, pos[b] // bt + 1)):
+            table[b, j] = free.pop()
+    # the pool holds the dense rows' slots, wherever the tables put them
+    for k in blocks:
+        if k not in attn:
+            blocks[k] = dense.blocks[k]
+            continue
+        leaves = []
+        for pool, rows in zip(blocks[k], dense.blocks[k]):
+            pool, rows = np.array(pool), np.asarray(rows)
+            for b, j in zip(*np.nonzero(table >= 0)):
+                seg = rows[:, b, :, j * bt:(j + 1) * bt]
+                pool[:, table[b, j]] = seg.transpose(0, 2, 1, 3).reshape(
+                    pool.shape[0], bt, -1)
+            leaves.append(jnp.asarray(pool))
+        blocks[k] = L.PagedKVCache(*leaves)
+    paged = DecodeCaches(blocks=blocks, cross=None)
+    valid = jnp.asarray([True, True, False])
+
+    for step in range(2):
+        slot = pos % C
+        for b in (0, 1):
+            if table[b, slot[b] // bt] < 0:
+                table[b, slot[b] // bt] = free.pop()
+        wblk = np.where([True, True, False], table[np.arange(B), slot // bt],
+                        0).astype(np.int32)
+        woff = np.where([True, True, False], slot % bt, 0).astype(np.int32)
+        tok = jnp.asarray(rng.integers(0, cfg.vocab_size, B), jnp.int32)
+        before = {k: [np.asarray(a) for a in paged.blocks[k]] for k in attn}
+        want, dense, _ = decode_step(params, cfg, tok, jnp.asarray(pos),
+                                     dense, row_valid=valid)
+        got, paged, _ = decode_step_paged(
+            params, cfg, tok, jnp.asarray(pos), paged, jnp.asarray(table),
+            jnp.asarray(wblk), jnp.asarray(woff), row_valid=valid)
+        np.testing.assert_array_equal(np.asarray(got)[:2],
+                                      np.asarray(want)[:2])
+        for k in attn:
+            for old, pool, rows in zip(before[k], paged.blocks[k],
+                                       dense.blocks[k]):
+                pool, rows = np.asarray(pool), np.asarray(rows)
+                for b in (0, 1):
+                    live = np.arange(max(0, pos[b] + 1 - C), pos[b] + 1) % C
+                    np.testing.assert_array_equal(
+                        _row_view(pool, table[b], live, hkv),
+                        rows[:, b][:, :, live])
+                changed = np.argwhere((pool != old).any(axis=(0, 3)))
+                assert {tuple(x) for x in changed} <= set(zip(wblk, woff))
+                assert (0, 0) in {tuple(x) for x in changed}  # vacant row
+        for k in blocks:
+            if k not in attn:
+                for got_s, want_s in zip(paged.blocks[k], dense.blocks[k]):
+                    np.testing.assert_array_equal(np.asarray(got_s),
+                                                  np.asarray(want_s))
+        pos = pos + np.array([1, 1, 0], np.int32)
+
+
+def test_decode_step_paged_moves_no_pool_slice(serving_setup,
+                                              paged_decode_program_check):
+    """The paged decode program keeps the stacked pool out of the layer
+    scan's ``xs``/``ys`` (compiled for the CPU here; for a described TPU
+    in ``test_tpu_compile.py``)."""
+    paged_decode_program_check(serving_setup[0])
 
 
 # ---------------------------------------------------------------------------

@@ -135,3 +135,18 @@ def test_quant_matmul_compiles(chip_compile):
                         ((128, D), jnp.bfloat16), qt)
     assert "tpu_custom_call" in text
 
+
+
+def test_paged_decode_keeps_the_pool_in_place(chip_compile, one_chip,
+                                              paged_decode_program_check):
+    """The paged decode step at granite widths, compiled for the chip: the
+    KV pool is read and written where it lies. A pool whose rows are one
+    head's ``hd`` = 64 wide is laid out block-innermost on a v5e, and the
+    compiler then relayouts each layer's slice of it, or the whole pool,
+    in every step."""
+    import dataclasses
+
+    from repro.configs import get_config
+    cfg = dataclasses.replace(get_config("granite-moe-1b-a400m"), n_layers=2)
+    paged_decode_program_check(cfg, sharding=one_chip, batch=8,
+                               max_len=512, block_tokens=16, n_blocks=300)
