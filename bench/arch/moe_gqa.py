@@ -1,11 +1,6 @@
-"""The benchmark's side of the model: configuration files, seeded weights
-and the plain reference forward pass.
-
-Nothing here imports the program. The weights are made from the seed by
-this module, in the layout the program's parameter tree uses (the harness
-checks the layout against the program before it hands them over), and the
-reference recomputes them from the same seed after the program has been
-freed, so it takes nothing the program made.
+"""Grouped-query attention with sparse SwiGLU experts in every layer
+(granite-moe, qwen3-moe): its sizes, seeded weights, plain reference,
+mapping onto the program, and operation and byte counts.
 
 The reference is the architecture written out in ``jax.numpy`` at float32
 with ``highest`` matmul precision: RMSNorm, RoPE (split halves), grouped-
@@ -14,17 +9,19 @@ with top-k gates renormalized, SwiGLU experts computed densely and weighted
 by the gates (no capacity, so no token is dropped), a final RMSNorm and the
 (tied or untied) LM head. It runs layer by layer over padded sequences so
 that one compiled program serves every request of a run.
+
+Nothing here imports the program except ``program_arch``, which builds the
+program's own configuration for these sizes.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
-import json
-from pathlib import Path
-from typing import Dict, List, Tuple
+from typing import Dict, Iterable, List, Tuple
 
 import numpy as np
 
-CONFIG_DIR = Path(__file__).resolve().parent / "configs"
+from bench.arch.common import make_weights, rmsnorm, rope
 
 #: Keys every configuration's ``arch`` block maps (to a key of the file, or
 #: to a literal value).
@@ -32,13 +29,6 @@ ARCH_KEYS = ("n_layers", "d_model", "vocab_size", "n_heads", "n_kv_heads",
              "head_dim", "num_experts", "top_k", "d_ff_expert", "norm_eps",
              "rope_theta", "tie_embeddings", "qk_norm", "norm_topk_prob",
              "attn_scale")
-
-
-def load_config(name: str, directory: Path = CONFIG_DIR) -> Dict:
-    path = directory / f"{name}.json"
-    if not path.is_file():
-        raise FileNotFoundError(f"no configuration {name!r} at {path}")
-    return json.loads(path.read_text())
 
 
 def dims(conf: Dict) -> Dict:
@@ -85,78 +75,41 @@ def param_spec(d: Dict) -> List[Tuple[Tuple[str, ...], tuple, str, float]]:
     return spec
 
 
-def seed_key(seed: int):
-    """A PRNG key from any non-negative integer seed (wider than 32 bits
-    included). The ``rbg`` generator draws on the chip's own random-bit
-    unit, which makes a billion weights in a fraction of the time threefry
-    takes; it is deterministic for a key on a given backend, and the
-    reference remakes the weights on the same device as the program."""
-    import jax
-    s = np.random.SeedSequence(int(seed)).generate_state(2)
-    return jax.random.fold_in(
-        jax.random.key(int(s[0]) & 0x7FFFFFFF, impl="rbg"),
-        int(s[1]) & 0x7FFFFFFF)
-
-
-def _nest(flat: Dict[Tuple[str, ...], object]) -> Dict:
-    out: Dict = {}
-    for path, v in flat.items():
-        node = out
-        for p in path[:-1]:
-            node = node.setdefault(p, {})
-        node[path[-1]] = v
-    return out
-
-
-@functools.lru_cache(maxsize=None)
-def _maker(spec_key):
-    import jax
-    import jax.numpy as jnp
-    spec = json.loads(spec_key)
-
-    @jax.jit
-    def make(key):
-        flat = {}
-        for i, (path, shape, dtype, scale) in enumerate(spec):
-            dt = jnp.dtype(dtype)
-            if scale == 0.0:
-                flat[tuple(path)] = jnp.ones(shape, dt)
-            else:
-                k = jax.random.fold_in(key, i)
-                flat[tuple(path)] = (jax.random.normal(k, shape, jnp.float32)
-                                     * scale).astype(dt)
-        return _nest(flat)
-    return make
-
-
 def make_params(d: Dict, seed: int) -> Dict:
-    """Every weight, made on the default device in one jitted call from
-    the seed, in the dtype it is served in."""
-    spec = [(list(p), list(s), t, c) for p, s, t, c in param_spec(d)]
-    return _maker(json.dumps(spec))(seed_key(seed))
+    return make_weights(param_spec(d), seed)
+
+
+# ---------------------------------------------------------------------------
+# The program's configuration
+# ---------------------------------------------------------------------------
+
+def program_arch(conf: Dict, d: Dict):
+    """The program's ArchConfig for this configuration: its registry entry
+    with every size the file states."""
+    from repro.configs import get_config
+    base = get_config(conf["serving"]["program_arch"])
+    attn = dataclasses.replace(base.attn, n_heads=d["n_heads"],
+                               n_kv_heads=d["n_kv_heads"],
+                               head_dim=d["head_dim"],
+                               rope_theta=float(d["rope_theta"]),
+                               qk_norm=bool(d["qk_norm"]))
+    moe = dataclasses.replace(base.moe, num_experts=d["num_experts"],
+                              top_k=d["top_k"], d_ff_expert=d["d_ff_expert"],
+                              norm_topk_prob=bool(d["norm_topk_prob"]))
+    arch = dataclasses.replace(base, n_layers=d["n_layers"],
+                               d_model=d["d_model"],
+                               vocab_size=d["vocab_size"], attn=attn, moe=moe,
+                               norm_eps=float(d["norm_eps"]),
+                               tie_embeddings=bool(d["tie_embeddings"]))
+    if abs(d["attn_scale"] - d["head_dim"] ** -0.5) > 1e-9:
+        raise ValueError("the program scales attention by 1/sqrt(head_dim); "
+                         f"the configuration states {d['attn_scale']}")
+    return arch
 
 
 # ---------------------------------------------------------------------------
 # Reference forward
 # ---------------------------------------------------------------------------
-
-def _rmsnorm(x, scale, eps):
-    import jax.numpy as jnp
-    return x * (1.0 / jnp.sqrt(jnp.mean(x * x, -1, keepdims=True) + eps)) \
-        * scale
-
-
-def _rope(x, theta):
-    """x: (B, S, H, hd), positions 0..S-1, split-halves rotation."""
-    import jax.numpy as jnp
-    S, hd = x.shape[1], x.shape[-1]
-    half = hd // 2
-    freqs = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = jnp.arange(S, dtype=jnp.float32)[:, None] * freqs
-    cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
-
 
 def _layer(d: Dict, x, w, token_block: int):
     """One decoder layer at float32. x: (B, S, D)."""
@@ -168,14 +121,14 @@ def _layer(d: Dict, x, w, token_block: int):
     eps = d["norm_eps"]
     f32 = lambda a: a.astype(jnp.float32)
 
-    h = _rmsnorm(x, f32(w["norm1"]), eps)
+    h = rmsnorm(x, f32(w["norm1"]), eps)
     q = (h @ f32(w["wq"])).reshape(B, S, H, hd)
     kk = (h @ f32(w["wk"])).reshape(B, S, Hkv, hd)
     v = (h @ f32(w["wv"])).reshape(B, S, Hkv, hd)
     if d["qk_norm"]:
-        q = _rmsnorm(q, f32(w["q_norm"]), 1e-6)
-        kk = _rmsnorm(kk, f32(w["k_norm"]), 1e-6)
-    q, kk = _rope(q, d["rope_theta"]), _rope(kk, d["rope_theta"])
+        q = rmsnorm(q, f32(w["q_norm"]), 1e-6)
+        kk = rmsnorm(kk, f32(w["k_norm"]), 1e-6)
+    q, kk = rope(q, d["rope_theta"]), rope(kk, d["rope_theta"])
     rep = H // Hkv
     qg = q.reshape(B, S, Hkv, rep, hd)
     mask = jnp.tril(jnp.ones((S, S), bool))
@@ -194,7 +147,7 @@ def _layer(d: Dict, x, w, token_block: int):
     o = jnp.concatenate(chunks, 1).reshape(B, S, H * hd)
     x = x + o @ f32(w["wo"])
 
-    h = _rmsnorm(x, f32(w["norm2"]), eps).reshape(B * S, D)
+    h = rmsnorm(x, f32(w["norm2"]), eps).reshape(B * S, D)
     probs = jax.nn.softmax(h @ w["router"].astype(jnp.float32), -1)
     top, idx = jax.lax.top_k(probs, k)
     if d["norm_topk_prob"]:
@@ -265,7 +218,77 @@ class Reference:
 def _head(d: Dict, params: Dict, x, positions):
     import jax.numpy as jnp
     xs = jnp.take_along_axis(x, positions[..., None], axis=1)   # (B, P, D)
-    xs = _rmsnorm(xs, params["final_norm"]["scale"].astype(jnp.float32),
-                  d["norm_eps"])
+    xs = rmsnorm(xs, params["final_norm"]["scale"].astype(jnp.float32),
+                 d["norm_eps"])
     head = params["embed"].T if d["tie_embeddings"] else params["lm_head"]
     return xs @ head.astype(jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# The ragged MoE FFN (gate/up kernel, then down kernel)
+# ---------------------------------------------------------------------------
+
+def expert_matrices(d: Dict):
+    """(K, N) of the three matrices of one expert: gate, up, down."""
+    D, F = d["d_model"], d["d_ff_expert"]
+    return ((D, F), (D, F), (F, D))
+
+
+def expert_hi_bytes(d: Dict) -> int:
+    """Bytes of one expert of one layer in bf16."""
+    return sum(2 * k * n for k, n in expert_matrices(d))
+
+
+def expert_lo_bytes(d: Dict, bits: int, group: int) -> int:
+    """Bytes of one expert of one layer at ``bits``-bit codes with one bf16
+    scale per ``group`` rows of K."""
+    return sum(k * n * bits // 8 + (k // group) * n * 2
+               for k, n in expert_matrices(d))
+
+
+def moe_ffn_work(d: Dict, forwards: Iterable[Dict], lo_bits: int,
+                 group: int) -> Dict[str, float]:
+    """Least FLOPs and bytes of the ragged expert FFN over some forwards.
+
+    Each forward is a ``moe_forward`` record: ``routed`` token-expert
+    assignments and ``active_hi``/``active_lo`` (layer, expert) cells that
+    received tokens, summed over layers. Every active expert's weights are
+    read once in the tier it is served from; every assignment's
+    activations are read and written once at bf16 (x in, h out and back
+    in, y out); every assignment costs the three matmuls of its expert."""
+    D, F = d["d_model"], d["d_ff_expert"]
+    hi_b, lo_b = expert_hi_bytes(d), expert_lo_bytes(d, lo_bits, group)
+    act_b = 2 * (2 * D + 2 * F)
+    flops = nbytes = 0.0
+    for f in forwards:
+        flops += f["routed"] * 2.0 * (2 * D * F + F * D)
+        nbytes += (f["active_hi"] * hi_b + f["active_lo"] * lo_b
+                   + f["routed"] * act_b)
+    return {"flops": flops, "bytes": nbytes}
+
+
+# ---------------------------------------------------------------------------
+# Whole-model FLOPs per token (for model_mfu)
+# ---------------------------------------------------------------------------
+
+def flops_per_token(d: Dict, ctx: float) -> float:
+    """Forward FLOPs of one token that attends to ``ctx`` positions
+    (itself included): attention projections and scores, router, the
+    top-k experts, and the LM head. Norms and softmaxes are left out."""
+    D, H, Hkv, hd = d["d_model"], d["n_heads"], d["n_kv_heads"], d["head_dim"]
+    E, k, F = d["num_experts"], d["top_k"], d["d_ff_expert"]
+    proj = 2 * (D * H * hd + 2 * D * Hkv * hd + H * hd * D)
+    scores = 2 * 2 * ctx * H * hd
+    router = 2 * D * E
+    experts = k * 2 * 3 * D * F
+    return d["n_layers"] * (proj + scores + router + experts) \
+        + 2 * D * d["vocab_size"]
+
+
+def prompt_flops(d: Dict, start: int, end: int) -> float:
+    """FLOPs of prefilling positions [start, end) of one sequence."""
+    n = end - start
+    # Position p attends to p + 1 positions; sum over the span.
+    ctx_sum = (start + 1 + end) * n / 2.0
+    return n * flops_per_token(d, 0) + (flops_per_token(d, 1)
+                                        - flops_per_token(d, 0)) * ctx_sum

@@ -3,11 +3,23 @@ own entry points, warm up, serve an open-loop schedule for the window,
 read the metrics, and decide ``correct`` against the plain reference.
 
 Everything a cell needs is found by name: the cell in ``BENCHMARK.json``,
-its configuration in ``bench/configs/<config>.json``, its traffic in
-``bench/traffic/<mix>.json``, each metric's reader in
-``bench/metrics/<metric>.py`` and the correctness limits and control in
-``bench/checks/<cell>.json``. Adding any of them is adding files and
-entries.
+its configuration in ``bench/configs/<config>.json``, the architecture
+module that configuration names (``"arch_module"``) in
+``bench/arch/<module>.py``, its traffic in ``bench/traffic/<mix>.json``,
+each metric's reader in ``bench/metrics/<metric>.py`` and the correctness
+limits and control in ``bench/checks/<cell>.json``. Adding any of them is
+adding files and entries.
+
+So a new architecture takes a cell by new files alone:
+
+- ``bench/arch/<module>.py``: sizes, seeded weights, the plain reference,
+  the mapping onto the program and the operation counts
+  (``bench/arch/__init__.py`` lists what it provides);
+- ``bench/configs/<config>.json``, naming ``"arch_module": "<module>"``;
+- ``bench/traffic/<mix>.json``;
+- ``bench/checks/<cell>.json``;
+- ``bench/metrics/<metric>.py`` for each new metric;
+- new entries in ``BENCHMARK.json``.
 """
 from __future__ import annotations
 
@@ -19,6 +31,7 @@ import math
 import shutil
 import time
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
@@ -62,12 +75,14 @@ class Cell:
     end_to_end: List[Dict]          # BENCHMARK.json metric entries
     per_layer: List[Dict]
     limits: Dict                    # number -> {"limit": ..., readings}
+    arch: ModuleType                # bench.arch.<conf["arch_module"]>
     control: Dict = dataclasses.field(default_factory=dict)
 
 
 def load_cell(name: str, bench_file: Path = CHECKOUT / "BENCHMARK.json",
               bench_dir: Path = BENCH_DIR) -> Cell:
-    from bench import model
+    from bench import arch
+    from bench.arch import common
     from bench.traffic import generator
     spec = json.loads(bench_file.read_text())
     cells = {w["name"]: w for w in spec["workloads"]}
@@ -77,7 +92,7 @@ def load_cell(name: str, bench_file: Path = CHECKOUT / "BENCHMARK.json",
     w = cells[name]
     confs = {c["name"]: c for c in spec["configs"]}
     conf_file = bench_file.parent / confs[w["config"]]["file"]
-    conf = model.load_config(conf_file.stem, conf_file.parent)
+    conf = common.load_config(conf_file.stem, conf_file.parent)
     mix = generator.load_mix(w["traffic"], bench_dir / "traffic")
 
     def applies(m):
@@ -90,7 +105,7 @@ def load_cell(name: str, bench_file: Path = CHECKOUT / "BENCHMARK.json",
                 per_layer=[m for m in spec["per_layer"] if applies(m)],
                 limits={k: v for k, v in checks.items()
                         if isinstance(v, dict) and "limit" in v},
-                control=checks.get("control", {}))
+                arch=arch.load(conf), control=checks.get("control", {}))
 
 
 def with_serve_args(cell: Cell, extra: List[str]) -> Cell:
@@ -137,7 +152,8 @@ class Run:
     window_s: float
     setup_s: float
     requests: List[Record]
-    dims: Dict
+    arch: ModuleType                # the cell's architecture module
+    dims: Dict                      # arch.dims(conf)
     peaks: Dict
     chips: int
     lo_bits: int
@@ -175,47 +191,23 @@ class Run:
 # The program under test
 # ---------------------------------------------------------------------------
 
-def program_arch(conf: Dict, d: Dict):
-    """The program's ArchConfig for this configuration: its registry entry
-    with every size the file states."""
-    from repro.configs import get_config
-    base = get_config(conf["serving"]["program_arch"])
-    attn = dataclasses.replace(base.attn, n_heads=d["n_heads"],
-                               n_kv_heads=d["n_kv_heads"],
-                               head_dim=d["head_dim"],
-                               rope_theta=float(d["rope_theta"]),
-                               qk_norm=bool(d["qk_norm"]))
-    moe = dataclasses.replace(base.moe, num_experts=d["num_experts"],
-                              top_k=d["top_k"], d_ff_expert=d["d_ff_expert"],
-                              norm_topk_prob=bool(d["norm_topk_prob"]))
-    arch = dataclasses.replace(base, n_layers=d["n_layers"],
-                               d_model=d["d_model"],
-                               vocab_size=d["vocab_size"], attn=attn, moe=moe,
-                               norm_eps=float(d["norm_eps"]),
-                               tie_embeddings=bool(d["tie_embeddings"]))
-    if abs(d["attn_scale"] - d["head_dim"] ** -0.5) > 1e-9:
-        raise ValueError("the program scales attention by 1/sqrt(head_dim); "
-                         f"the configuration states {d['attn_scale']}")
-    return arch
-
-
-def check_layout(arch, d: Dict) -> None:
-    """The benchmark's weights have the program's tree, shapes and dtypes."""
+def check_layout(arch: ModuleType, cfg, d: Dict) -> None:
+    """The weights ``arch`` makes have the tree, shapes and dtypes of the
+    program's under its configuration ``cfg``."""
     import jax
-    from bench import model
     from repro.models import init_params
-    want = jax.eval_shape(lambda k: init_params(k, arch),
+    want = jax.eval_shape(lambda k: init_params(k, cfg),
                           jax.random.PRNGKey(0))
     flat = {tuple(getattr(p, "key", str(p)) for p in path): (tuple(v.shape),
                                                               str(v.dtype))
             for path, v in jax.tree_util.tree_flatten_with_path(want)[0]}
-    mine = {tuple(p): (tuple(s), t) for p, s, t, _ in model.param_spec(d)}
+    mine = {tuple(p): (tuple(s), t) for p, s, t, _ in arch.param_spec(d)}
     if flat != mine:
         raise ValueError(f"weight layout differs from the program's: "
                          f"{sorted(set(flat.items()) ^ set(mine.items()))}")
 
 
-def build_engine(conf: Dict, arch, params: Dict, obs=None):
+def build_engine(conf: Dict, cfg, params: Dict, obs=None):
     """The engine, through the launcher's own functions (``build_backend``,
     ``build_engine_config``), ``make_backend``, ``EngineConfig`` and
     ``InferenceEngine``."""
@@ -228,8 +220,8 @@ def build_engine(conf: Dict, arch, params: Dict, obs=None):
     ecfg = build_engine_config(args)
     if sv.get("capacity_factor") == "drop_free":
         ecfg = dataclasses.replace(
-            ecfg, capacity_factor=arch.moe.num_experts / arch.moe.top_k)
-    return InferenceEngine(arch, params, backend, ecfg, obs=obs), args
+            ecfg, capacity_factor=cfg.moe.num_experts / cfg.moe.top_k)
+    return InferenceEngine(cfg, params, backend, ecfg, obs=obs), args
 
 
 class CompileMeter:
@@ -391,16 +383,15 @@ def finish(engine, sample: List[Record], limit_s: float) -> float:
     return time.perf_counter() - t0
 
 
-def reference_gaps(d: Dict, seed: int, sample: List[Dict],
-                   seq_len: int) -> Dict:
+def reference_gaps(arch: ModuleType, d: Dict, seed: int,
+                   sample: List[Dict], seq_len: int) -> Dict:
     """Over every served position of the sample, the gap by which the
     served token's reference logit lies below the reference's best: the
     widest, the mean and the 90th percentile, the share of positions where
     the two agree, and whether every reference logit was finite."""
     import jax
     import jax.numpy as jnp
-    from bench import model
-    params = model.make_params(d, seed)
+    params = arch.make_params(d, seed)
     P = max(len(s["served"]) for s in sample)
     rows = -(-len(sample) // REF_ROWS) * REF_ROWS
     toks = np.zeros((rows, seq_len), np.int32)
@@ -415,7 +406,7 @@ def reference_gaps(d: Dict, seed: int, sample: List[Dict],
         pos[i, m:] = n - 1 + m - 1
         nxt[i, :m] = s["served"]
         valid[i, :m] = True
-    ref = model.Reference(d, params)
+    ref = arch.Reference(d, params)
     parts, finite = [], True
     for b0 in range(0, rows, REF_ROWS):
         sl = slice(b0, b0 + REF_ROWS)
@@ -472,13 +463,13 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     replaces the mix's offered load (the knee sweep and the calibration;
     ``bench/run.py`` never sets it)."""
     import jax
-    from bench import flops, model
+    from bench.arch import common
     from bench.traffic import generator
     from repro.obs import Observability, ObsConfig
 
-    d = model.dims(cell.conf)
+    d = cell.arch.dims(cell.conf)
     dev = device_info(jax, cell.chips)
-    peaks = flops.load_peaks(dev["kind"])
+    peaks = common.load_peaks(dev["kind"])
     meter = CompileMeter()
     marks = {}
 
@@ -488,14 +479,14 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                       r.tokens) for r in reqs]
     log(f"traffic {json.dumps(generator.describe(reqs))}")
 
-    arch = program_arch(cell.conf, d)
-    check_layout(arch, d)
-    params = model.make_params(d, seed)
+    cfg = cell.arch.program_arch(cell.conf, d)
+    check_layout(cell.arch, cfg, d)
+    params = cell.arch.make_params(d, seed)
     jax.block_until_ready(params)
     marks["weights"] = time.perf_counter()
     obs = Observability(ObsConfig(metrics=False, trace_capacity=1 << 20)) \
         if trace else None
-    engine, args = build_engine(cell.conf, arch, params, obs=obs)
+    engine, args = build_engine(cell.conf, cfg, params, obs=obs)
     del params
     marks["engine"] = time.perf_counter()
     c0 = (meter.seconds, meter.count, meter.hits)
@@ -551,7 +542,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     cnt1 = engine.counters
     st1 = backend.stats()
     run = Run(
-        window_s=float(seconds), setup_s=setup_s, requests=records, dims=d,
+        window_s=float(seconds), setup_s=setup_s, requests=records,
+        arch=cell.arch, dims=d,
         peaks=peaks, chips=cell.chips, lo_bits=int(args.lo_bits),
         group=int(getattr(backend, "group_size", 64)),
         counters={k: float(cnt1[k] - cnt0.get(k, 0)) for k in cnt1},
@@ -627,7 +619,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         seq_len = int(cell.mix["prompt_len"]["max"]
                       + cell.mix["output_len"]["max"])
         t_ref = time.perf_counter()
-        readings = reference_gaps(d, seed, sample, seq_len)
+        readings = reference_gaps(cell.arch, d, seed, sample, seq_len)
         extra["reference_s"] = time.perf_counter() - t_ref
         extra["readings"] = readings
     checks, correct = judge(cell.limits, readings)

@@ -20,6 +20,7 @@ TINY_CONF: Dict = {
     "num_local_experts": 8, "num_experts_per_tok": 2, "vocab_size": 512,
     "rms_norm_eps": 1e-6, "rope_theta": 10000, "tie_word_embeddings": True,
     "reduced": [],
+    "arch_module": "moe_gqa",
     "arch": {
         "n_layers": "num_hidden_layers", "d_model": "hidden_size",
         "vocab_size": "vocab_size", "n_heads": "num_attention_heads",
@@ -62,7 +63,7 @@ def tiny_cell():
     """A Cell over the tiny configuration, with every metric the
     benchmark declares."""
     import json
-    from bench import harness
+    from bench import arch, harness
     spec = json.loads((harness.CHECKOUT / "BENCHMARK.json").read_text())
     checks = copy.deepcopy(TINY_LIMITS)
     return harness.Cell(
@@ -70,7 +71,7 @@ def tiny_cell():
         mix=copy.deepcopy(TINY_MIX),
         end_to_end=spec["end_to_end"], per_layer=spec["per_layer"],
         limits={k: v for k, v in checks.items() if "limit" in v},
-        control=checks["control"])
+        arch=arch.load(TINY_CONF), control=checks["control"])
 
 
 def rehearse(seed: int, seconds: float, trace: bool = False,
@@ -78,10 +79,11 @@ def rehearse(seed: int, seconds: float, trace: bool = False,
     """One run of the tiny cell on this CPU (``control``: with the
     control's flags). Peaks are the v5e row: the arithmetic runs, the
     numbers mean nothing."""
-    from bench import flops, harness
+    from bench import harness
+    from bench.arch import common
     os.environ["REPRO_MOE_GEMM"] = "pallas"      # kernels in interpret mode
-    real = flops.load_peaks
-    flops.load_peaks = lambda kind, *a, **k: real("TPU v5 lite")
+    real = common.load_peaks
+    common.load_peaks = lambda kind, *a, **k: real("TPU v5 lite")
     cell = tiny_cell()
     if control:
         cell = harness.with_serve_args(cell, cell.control["serve_args"])
@@ -89,4 +91,4 @@ def rehearse(seed: int, seconds: float, trace: bool = False,
         return harness.run_cell(cell, seed, seconds, trace,
                                 time.perf_counter())
     finally:
-        flops.load_peaks = real
+        common.load_peaks = real

@@ -529,22 +529,22 @@ def main(argv=None) -> None:
     import run as entry
     entry.setup_environment()
     import jax
-    from bench import devtrace, harness, model
+    from bench import devtrace, harness
     from bench.traffic import generator
     from repro.obs import Observability, ObsConfig
     if jax.devices()[0].platform != "tpu":
         entry.fail("bench/spans.py runs only on a TPU", 3)
     cell = harness.load_cell(args.workload)
-    d = model.dims(cell.conf)
+    d = cell.arch.dims(cell.conf)
     reqs = generator.schedule(cell.mix, d["vocab_size"], args.seed,
                               args.seconds)
     records = [harness.Record(r.due_s, r.prompt_len, r.max_new_tokens,
                               r.workload, r.tokens) for r in reqs]
-    arch = harness.program_arch(cell.conf, d)
-    harness.check_layout(arch, d)
+    cfg = cell.arch.program_arch(cell.conf, d)
+    harness.check_layout(cell.arch, cfg, d)
     obs = Observability(ObsConfig(metrics=False, trace_capacity=1 << 20))
-    engine, _ = harness.build_engine(cell.conf, arch,
-                                     model.make_params(d, args.seed),
+    engine, _ = harness.build_engine(cell.conf, cfg,
+                                     cell.arch.make_params(d, args.seed),
                                      obs=obs)
     warm = harness.warm_up(engine, cell.mix, reqs, d["vocab_size"],
                            args.seed)
